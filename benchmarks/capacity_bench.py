@@ -173,6 +173,8 @@ def run_distributed():
 
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: curve stops at 10^4 devices")

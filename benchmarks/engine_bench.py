@@ -563,6 +563,8 @@ def run_segmented_bench(args):
 
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--devices", type=int, default=None)
     ap.add_argument("--clusters", type=int, default=None)

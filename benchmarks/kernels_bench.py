@@ -82,6 +82,8 @@ def bench_attention_traffic_model():
 
 
 def main(full: bool = False):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bench_trust_aggregate()
     bench_attention_traffic_model()
     if full:

@@ -107,6 +107,8 @@ def run(fast: bool = False, out_path: str = "BENCH_population.json"):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(fast="--fast" in sys.argv,
         out_path=next((a.split("=", 1)[1] for a in sys.argv
                        if a.startswith("--out=")),

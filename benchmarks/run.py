@@ -6,7 +6,9 @@ import sys
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
     from . import attack_bench, figures, kernels_bench, roofline
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     print("benchmark,metric,value")
     if quick:

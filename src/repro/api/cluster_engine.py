@@ -43,7 +43,12 @@ all three controllers on both execution paths (with the jnp aggregation
 path, ``use_kernel=False``).  Across G>1 shards, scheduling, actions,
 counters, energies and the frequency table stay exact (single-contributor
 psums add zeros; integer counts are exact); only the Eqn-19 sums
-reassociate, so losses match to rtol ~1e-5.
+reassociate, so losses match to rtol ~1e-5 where matmuls run in f32.  On a
+TPU at the default matmul precision (bf16 passes for f32 operands) local
+training amplifies those ulps: over the 40 rounds of the paper's
+16-device MLP scenario on four v5e chips, losses differed by up to 1.7e-4
+relative while every exact column still matched (``chip_smoke.py --chips
+4`` checks both precisions).
 
 Two deliberate replications keep the collective count at two: the Markov
 channel draws the full-fleet categorical on every shard (the transition
@@ -61,7 +66,6 @@ import logging
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.control import policy as ctl_policy
@@ -70,12 +74,10 @@ from repro.core.clustering import tolerance_bound
 from repro.core.energy import round_energy
 from repro.core.envs import OBS_DIM
 from repro.core.trust import (belief, gradient_diversity, learning_quality,
-                              trust_weights, trust_weighted_average,
-                              update_reputation)
+                              trust_weights, update_reputation)
 from repro.core.twin import TwinState, calibrate, calibrated_freq
 from repro.data.federated import sample_member_batch
 
-from .components import WeightedAggregator
 from .engine import DeviceScaleEngine, FleetState, _flatten_params
 from .placement import shard_map_placement
 from .spec import ShardingSpec
@@ -292,18 +294,6 @@ class ClusterMajorEngine(DeviceScaleEngine):
         upd = jax.lax.dynamic_update_slice(full, new, (lo,))
         return jnp.where(mine, upd, full)
 
-    def _agg_call(self, new, w, mask):
-        """Eqn-6 aggregation inside the shard program.  Weighted rules run
-        the pure-jnp oracle (`trust_weighted_average`) — identical math to
-        their ``use_kernel=False`` path — instead of dispatching a Pallas
-        kernel from inside shard_map; masked robust rules are jnp already."""
-        ag = self.aggregator
-        if isinstance(ag, WeightedAggregator):
-            w2 = ag._effective_weights(w, mask)
-            w2 = w2 * mask.astype(w2.dtype)
-            return trust_weighted_average(new, w2)
-        return ag(new, w, mask)
-
     # ------------------------------------------------------------------ #
     # the per-shard round (traced under shard_map)
     # ------------------------------------------------------------------ #
@@ -399,7 +389,9 @@ class ClusterMajorEngine(DeviceScaleEngine):
                     w if spec.aggregator.kind == "trust" else mask_f / cnt,
                     spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
             else:
-                agg = self._agg_call(new, w, mask)
+                # Eqn 6 as the spec asks: the masked Pallas kernel when
+                # use_kernel is on, the jnp rule otherwise
+                agg = self.aggregator(new, w, mask)
 
             losses = task.losses(new, batch)
             true_freq = tslice(state.twins.freq + state.twins.freq_dev,
@@ -588,11 +580,11 @@ class ClusterMajorEngine(DeviceScaleEngine):
         pspecs = self._cm_pspecs()
         dev = P(self._ax)
         m_specs = {"a": P(), "dur": P(), "consumed": P(), "loss": P()}
-        sm = shard_map(
+        sm = jax.shard_map(
             self._cm_round_local, mesh=self.placement.mesh,
             in_specs=(pspecs, P(), P(), P(), P(), dev, dev, dev, dev),
             out_specs=(pspecs, P(), P(), m_specs),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sm)
 
     def _cm_event_round(self, state, c, a_raw, members=None, mask=None):
@@ -618,10 +610,10 @@ class ClusterMajorEngine(DeviceScaleEngine):
 
         f_specs = {"cluster_loss": P(), "mean_freq": P(),
                    "channel_good_frac": P(), "cluster_freq": P()}
-        sm = shard_map(
+        sm = jax.shard_map(
             fn, mesh=self.placement.mesh,
             in_specs=(pspecs, P(), P(), P(), dev, dev, dev, dev),
-            out_specs=(f_specs, P()), check_rep=False)
+            out_specs=(f_specs, P()), check_vma=False)
         return jax.jit(sm)
 
     def _build_aux_fn(self):
@@ -645,9 +637,9 @@ class ClusterMajorEngine(DeviceScaleEngine):
             vec = jax.lax.psum(vec, ax)
             return vec[:C_pad], vec[C_pad:] / n
 
-        sm = shard_map(aux, mesh=self.placement.mesh,
+        sm = jax.shard_map(aux, mesh=self.placement.mesh,
                        in_specs=(pspecs, dev, dev, dev, dev),
-                       out_specs=(P(), P()), check_rep=False)
+                       out_specs=(P(), P()), check_vma=False)
         return jax.jit(sm)
 
     # ------------------------------------------------------------------ #
@@ -688,12 +680,12 @@ class ClusterMajorEngine(DeviceScaleEngine):
 
         ys_specs = {k: P() for k in ("t", "cluster", "a", "dur",
                                      "consumed", "loss")}
-        sm = shard_map(
+        sm = jax.shard_map(
             local, mesh=self.placement.mesh,
             in_specs=(pspecs, P(), ctl_spec, P(), P(), P(),
                       dev, dev, dev, dev),
             out_specs=((pspecs, P(), ctl_spec, P(), P(), P()), ys_specs),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sm)
 
     def run_scanned(self, K: int, *, eval_final: bool = True):

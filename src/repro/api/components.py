@@ -54,7 +54,7 @@ from repro.core.robust import AGGREGATORS as ROBUST_RULES
 from repro.core.robust import MASKED_AGGREGATORS as MASKED_RULES
 from repro.core.trust import trust_weighted_average
 from repro.core.twin import calibrated_freq
-from repro.kernels.ops import (INTERPRET, trust_aggregate_global_tree,
+from repro.kernels.ops import (trust_aggregate_global_tree,
                                trust_aggregate_tree)
 
 from .registry import (register_aggregator, register_controller,
@@ -105,8 +105,7 @@ class WeightedAggregator:
     def __call__(self, client_params, weights, mask=None):
         weights = self._effective_weights(weights, mask)
         if self.use_kernel:
-            return trust_aggregate_tree(client_params, weights, mask,
-                                        interpret=INTERPRET)
+            return trust_aggregate_tree(client_params, weights, mask)
         if mask is not None:
             weights = weights * mask.astype(weights.dtype)
         return trust_weighted_average(client_params, weights)
@@ -119,8 +118,7 @@ class WeightedAggregator:
         in-VMEM before the staleness-weighted average)."""
         weights = self._effective_weights(weights, mask)
         return trust_aggregate_global_tree(
-            client_params, weights, mask, cluster_stack, staleness_w, c,
-            interpret=INTERPRET)
+            client_params, weights, mask, cluster_stack, staleness_w, c)
 
 
 class RobustAggregator:

@@ -282,9 +282,7 @@ class DeviceScaleEngine:
 
         self.fused = True if fused is None else bool(fused)
         # donate the FleetState buffers so the round updates in place
-        # (CPU ignores donation and warns, so only request it elsewhere)
-        donate = (0,) if jax.default_backend() != "cpu" else ()
-        jit_kw = dict(donate_argnums=donate)
+        jit_kw = dict(donate_argnums=(0,))
         if self.placement.is_sharded:
             # pin the round's output placement so the FleetState carry keeps
             # its leaf-group shardings instead of drifting to whatever the
@@ -599,7 +597,7 @@ class DeviceScaleEngine:
         if fuse_global:
             # one kernel pass: Eqn-6 reduction of the member updates +
             # substitution into the cluster stack + the Eqn-19 average
-            # ((n_clusters + C, BLOCK) tiles per grid step; the per-shard
+            # ((n_clusters + C, block) tiles per grid step; the per-shard
             # unit under a mesh placement)
             gparams = self.aggregator.aggregate_with_global(
                 new, w, mask, state.cluster_params,
@@ -756,8 +754,7 @@ class DeviceScaleEngine:
             return jax.lax.scan(body, (state, times, ctl, energy), None,
                                 length=K)
 
-        donate = (0,) if jax.default_backend() != "cpu" else ()
-        jit_kw = dict(donate_argnums=donate)
+        jit_kw = dict(donate_argnums=(0,))
         if self.placement.is_sharded:
             # carry: FleetState by leaf group, the per-cluster event-time
             # vector with the cluster stack, policy carry + energy tally

@@ -16,6 +16,8 @@ import dataclasses
 import json
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .federation import Federation
 from . import scenarios  # noqa: F401  (populates SCENARIOS)
 from .registry import SCENARIOS
@@ -88,6 +90,7 @@ def _config_error(e: BaseException) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.list:
         for name in SCENARIOS.names():
             print(f"{name:16s} {SCENARIOS.get(name).__doc__.strip()}")

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 GOOD, MEDIUM, BAD = 0, 1, 2
-NOISE_MEAN_DB = jnp.array([0.1, 0.3, 0.5])
+NOISE_MEAN_DB = (0.1, 0.3, 0.5)   # host tuple: importing starts no backend
 
 
 class ChannelParams(NamedTuple):
@@ -47,7 +47,7 @@ def channel_rate(state, key, params: ChannelParams = ChannelParams(),
     ``state.shape`` — a device's channel noise is then invariant to the
     padded membership width, which is what pins padded, sharded, and
     population-stacked rounds to the same realization."""
-    lam = NOISE_MEAN_DB[state]
+    lam = jnp.asarray(NOISE_MEAN_DB, jnp.float32)[state]
     if members is None:
         noise = jax.random.poisson(key, lam, state.shape)
     else:

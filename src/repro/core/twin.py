@@ -35,10 +35,13 @@ def init_twins(key, n: int, *, freq_lo=0.5, freq_hi=2.0,
     kf, kd = jax.random.split(key)
     freq = jax.random.uniform(kf, (n,), minval=freq_lo, maxval=freq_hi)
     data = jax.random.randint(kd, (n,), data_lo, data_hi).astype(jnp.float32)
-    z = jnp.zeros((n,), jnp.float32)
+    # a fresh zeros per leaf: the engines donate `FleetState`, and XLA
+    # refuses to donate one buffer twice
+    z = lambda: jnp.zeros((n,), jnp.float32)               # noqa: E731
     return TwinState(loss=jnp.full((n,), jnp.inf), freq=freq,
-                     freq_dev=z, dev_estimate=z, energy=z, data_size=data,
-                     alpha=jnp.ones((n,)), beta=z, router_entropy=z)
+                     freq_dev=z(), dev_estimate=z(), energy=z(),
+                     data_size=data, alpha=jnp.ones((n,)), beta=z(),
+                     router_entropy=z())
 
 
 def sample_deviation(key, twins: TwinState, max_dev: float = 0.2) -> TwinState:

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -2.0e38
 
@@ -108,7 +107,7 @@ def flash_attention(q, k, v, *, bq: int = 256, bk: int = 256,
             pltpu.VMEM((bq, 1), jnp.float32),     # running sum l
             pltpu.VMEM((bq, dv), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

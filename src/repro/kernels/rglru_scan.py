@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 
 def _kernel(a_ref, bx_ref, y_ref, hout_ref, h_ref, *, seq_len: int):
@@ -57,7 +56,7 @@ def rglru_scan(a, bx, *, bw: int = 1024, interpret: bool = False):
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(a, bx)

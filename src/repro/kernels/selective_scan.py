@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 
 def _kernel(xc_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
@@ -72,7 +71,7 @@ def selective_scan(xc, dt, Bc, Cc, A, *, bd: int = 512,
             jax.ShapeDtypeStruct((B, Di, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xc, dt, Bc, Cc, A)

@@ -110,7 +110,6 @@ def moe_forward(p, cfg: ArchConfig, x):
     mesh = _ep_mesh_axes(cfg)
 
     if mesh is not None and E % mesh.shape["data"] == 0:
-        from jax.experimental.shard_map import shard_map
         nd = mesh.shape["data"]
         cap_l = int(max(1, (T // nd) * K * cfg.capacity_factor // E))
         e_flat = gate_idx.reshape(T * K)
@@ -130,7 +129,7 @@ def moe_forward(p, cfg: ArchConfig, x):
             return (y_tok.reshape(Tl, K, -1) *
                     gv_l[..., None].astype(x.dtype)).sum(axis=1)
 
-        buf, slot, keep = shard_map(
+        buf, slot, keep = jax.shard_map(
             dispatch, mesh=mesh,
             in_specs=(P("data", None), P("data")),
             out_specs=(P("data", None, None), P("data"), P("data")),
@@ -141,7 +140,7 @@ def moe_forward(p, cfg: ArchConfig, x):
         y_e = jnp.einsum("ecf,efd->ecd", h, p["wd"])
         y_e = _constrain_ep(y_e, ("data", None, "model"), cfg)
 
-        y = shard_map(
+        y = jax.shard_map(
             combine, mesh=mesh,
             in_specs=(P("data", None, "model"), P("data"), P("data"),
                       P("data", None)),

@@ -33,14 +33,14 @@ SPAN_SCHEMA = "span/1"
 
 
 def fence(tree: Any) -> Any:
-    """`jax.block_until_ready`, tolerating non-array pytrees and
-    environments where jax is absent (the registry is zero-dep; spans
-    only need jax when actually fencing device values)."""
+    """`jax.block_until_ready` on ``tree`` (non-array leaves pass through).
+    Without jax installed there is nothing to wait for (the registry is
+    zero-dep); a device error raised while waiting propagates."""
     try:
         import jax
-        return jax.block_until_ready(tree)
-    except Exception:
+    except ImportError:
         return tree
+    return jax.block_until_ready(tree)
 
 
 class Span:

@@ -449,8 +449,7 @@ class PopulationEngine:
             return jax.lax.scan(body, (state, times, ctl, energy), None,
                                 length=K)
 
-        jit_kw = dict(
-            donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
+        jit_kw = dict(donate_argnums=(0,))
         if self.mesh is not None:
             pop = NamedSharding(self.mesh, PartitionSpec(self.pop_axis))
             carry_sh = (jax.tree.map(lambda _: pop, self.state), pop,

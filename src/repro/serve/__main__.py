@@ -41,6 +41,8 @@ import subprocess
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .runner import latest_resumable
 from .service import (CKPT_REQ, LOG_FILE, STOP_REQ, RunDir, pid_alive,
                       run_service, service_status)
@@ -478,6 +480,7 @@ def cmd_pool(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     return {"start": cmd_start, "resume": cmd_resume,
             "status": cmd_status, "metrics": cmd_metrics,
             "checkpoint": cmd_checkpoint, "pool": cmd_pool,

@@ -161,6 +161,34 @@ def test_fleet_state_is_device_resident_pytree():
     assert int(fed.engine.state.round) == fed.engine.agg_count > 0
 
 
+def _buffer_pointer(leaf):
+    if jnp.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+        leaf = jax.random.key_data(leaf)
+    return leaf.unsafe_buffer_pointer()
+
+
+def test_fleet_state_leaves_own_their_buffers_under_donation():
+    """The rounds donate `FleetState` on every backend, and XLA refuses to
+    donate one buffer twice: every leaf of a fresh engine owns its buffer,
+    and back-to-back scans consume (delete) the state they were given."""
+    data, parts = _data(seed=16)
+    spec = FederationSpec(
+        fleet=FleetSpec(n_devices=8),
+        clustering=api.ClusteringSpec(n_clusters=2),
+        controller=ControllerSpec("fixed", {"a": 2}),
+        execution="scanned", rounds=3, sim_seconds=1e9, local_batch=32,
+        seed=16)
+    eng = Federation.from_spec(spec, data=data, parts=parts).engine
+    ptrs = [_buffer_pointer(l) for l in jax.tree.leaves(eng.state)]
+    assert len(set(ptrs)) == len(ptrs)
+    given = eng.state
+    eng.run_scanned(3, eval_final=False)
+    assert given.rep.is_deleted()
+    tr = eng.run_scanned(3)
+    assert eng.round == 6
+    assert all(np.isfinite(r.loss) for r in tr.records)
+
+
 def test_exact_shape_mode_drives_robust_aggregators():
     """Aggregators without mask support (krum-family rank statistics; the
     ±inf-padded sorts give median and trimmed_mean masked variants) run

@@ -42,8 +42,9 @@ def _data(n=512, dim=24, devices=8, seed=0):
 
 def _spec(seed, mesh=(1,), impl=None, **kw):
     kw.setdefault("controller", ControllerSpec("fixed", {"a": 3}))
-    # the cluster-major engine aggregates with the jnp oracle; the
-    # unsharded reference must run the same rule for bit-exact parity
+    # bit-exact parity needs one Eqn-6/19 reduction on both sides: with the
+    # kernel, the unsharded engine fuses Eqn 19 into it and the
+    # cluster-major engine psums Eqn 19 after the masked kernel
     kw.setdefault("aggregator", AggregatorSpec("trust",
                                                {"use_kernel": False}))
     kw.setdefault("fleet", FleetSpec(n_devices=8))

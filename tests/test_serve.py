@@ -352,6 +352,53 @@ def test_chaos_sigkill_recovery_trace_parity(tmp_path, monkeypatch,
     assert st["checkpoint_manifest"]["rounds"] == 6
 
 
+# --------------------------------------------------------------------- #
+# parents that spawn a service child leave the accelerator to the child
+# --------------------------------------------------------------------- #
+_PARENT_SUBPROC = r"""
+import sys
+from jax._src import xla_bridge
+import repro.api, repro.kernels, repro.pop, repro.serve
+assert not xla_bridge.backends_are_initialized(), "backend started on import"
+from repro.serve import __main__ as cli, chaos
+
+
+class _Exited:                          # a child that died at once
+    pid, returncode = 0, 1
+    poll = wait = lambda self: 1
+
+
+cli._spawn = lambda rd, argv: 0
+chaos.spawn_service = lambda *a, **k: _Exited()
+root = sys.argv[1]
+assert cli.main(["start", "--run-dir", root + "/svc"]) == 0
+assert cli.main(["pool", "start", "--run-dir", root + "/pool",
+                 "--replicates", "2"]) == 0
+assert cli.main(["chaos", "--run-dir", root + "/chaos",
+                 "--total-segments", "1", "--kills", "0",
+                 "--max-restarts", "0"]) == 1
+print("BACKEND", xla_bridge.backends_are_initialized())
+"""
+
+
+def test_spawning_parents_start_no_jax_backend(tmp_path):
+    """A chip belongs to one process: `serve start`, `pool start` and
+    `chaos` re-exec a child to run the federation, so neither importing
+    `repro` nor the parent's own work may start a JAX backend."""
+    import subprocess
+    import sys
+    import repro.serve
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(repro.serve.__file__))))
+    env = dict(os.environ, PYTHONPATH=src + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run(
+        [sys.executable, "-c", _PARENT_SUBPROC, str(tmp_path)], env=env,
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "BACKEND False" in out.stdout
+
+
 def test_rundir_pid_and_requests(tmp_path):
     rd = RunDir(str(tmp_path)).ensure()
     assert rd.running_pid() is None
