@@ -656,16 +656,17 @@ class ClusterMajorEngine(DeviceScaleEngine):
                 state, times, ctl, energy, ftbl, ch3 = carry
                 c = jnp.argmin(times).astype(jnp.int32)
                 t = times[c]
-                feats, obs48 = self._cm_feats_local(
-                    state, ftbl, ch3, c, mskslot_l, pol.needs_obs)
-                cobs = ctl_policy.CtlObs(
-                    round=state.round, cluster=c, queue=state.queue,
-                    cluster_loss=feats["cluster_loss"],
-                    cluster_freq=feats["cluster_freq"],
-                    mean_freq=feats["mean_freq"],
-                    channel_good_frac=feats["channel_good_frac"],
-                    energy_used=energy, dqn_obs=obs48)
-                a_raw, ctl = pol.step(ctl, cobs)
+                with jax.named_scope("fl.control"):
+                    feats, obs48 = self._cm_feats_local(
+                        state, ftbl, ch3, c, mskslot_l, pol.needs_obs)
+                    cobs = ctl_policy.CtlObs(
+                        round=state.round, cluster=c, queue=state.queue,
+                        cluster_loss=feats["cluster_loss"],
+                        cluster_freq=feats["cluster_freq"],
+                        mean_freq=feats["mean_freq"],
+                        channel_good_frac=feats["channel_good_frac"],
+                        energy_used=energy, dqn_obs=obs48)
+                    a_raw, ctl = pol.step(ctl, cobs)
                 state, ftbl, ch3, m = self._cm_round_local(
                     state, ftbl, ch3, c, a_raw,
                     oos_l, misb_l, mskslot_l, validc_l)
@@ -694,25 +695,17 @@ class ClusterMajorEngine(DeviceScaleEngine):
             raise ValueError(
                 f"controller {type(self.controller).__name__} has no "
                 "scan_policy(); use the event-heap run() instead")
-        pol = scan_policy()
         K = int(K)
-        args = (self.state, self._scan_times, pol.state,
-                self._scan_energy_start(), self._ftbl, self._ch3,
-                *self._statics)
-        fn = self._scan_cache.get(K)
+        with self._obs_span("prepare"):
+            pol = scan_policy()
+            args = (self.state, self._scan_times, pol.state,
+                    self._scan_energy_start(), self._ftbl, self._ch3,
+                    *self._statics)
+            fn = self._scan_cache.get(K)
         if fn is None:
-            fn = self._instrument_compile(
-                f"cm_run_scanned[K={K}]", self._build_scan_fn(K, pol),
-                args)
-            self._scan_cache[K] = fn
-        if self.obs is None:
-            out = fn(*args)
-        else:
-            with self.obs.span("round", mode="scanned", rounds=K) as sp:
-                out = fn(*args)
-                sp.mark("dispatch")
-                jax.block_until_ready(out)
-        (state, times, _, energy_end, ftbl, ch3), ys = out
+            fn = self._compile_scan(K, pol, args, "cm_run_scanned")
+        (state, times, _, energy_end, ftbl, ch3), ys = self._dispatch_scan(
+            fn, args, K)
         self.state = state
         self._scan_times = times
         self._ftbl, self._ch3 = ftbl, ch3

@@ -82,7 +82,6 @@ the same controller protocol and emits the same `RoundRecord` trace.
 """
 from __future__ import annotations
 
-import contextlib
 import heapq
 from typing import (Any, NamedTuple, Optional, Protocol, runtime_checkable)
 
@@ -104,6 +103,7 @@ from repro.core.twin import (calibrate, calibrated_freq, init_twins,
                              sample_deviation, TwinState)
 from repro.data.federated import padded_partition, sample_member_batch
 from repro.faults import FaultModel
+from repro.obs import annotate, compiles
 
 from . import placement as placement_lib
 from .components import ControllerCtx
@@ -323,6 +323,7 @@ class DeviceScaleEngine:
         # round program, so traces stay bit-identical with it attached
         self.obs = None
         self._obs_summary_fn = None
+        compiles.install()              # counts every compile, obs or not
         # control plane: jitted host ctx features / observation builders
         # + compiled scan paths
         self._features_fn = jax.jit(self._ctl_features)
@@ -380,8 +381,10 @@ class DeviceScaleEngine:
             obs.publish_static(self)
 
     def _obs_span(self, name: str, fence_on=None, **attrs):
+        """The span ``name`` of the attached recorder, or with none
+        attached the profiler annotation ``fl.<name>`` alone."""
         if self.obs is None:
-            return contextlib.nullcontext()
+            return annotate("fl." + name)
         return self.obs.span(name, fence_on=fence_on, **attrs)
 
     def _instrument_compile(self, name: str, fn, args):
@@ -403,6 +406,25 @@ class DeviceScaleEngine:
             hlo = None
         self.obs.record_compile(name, sp.dur_s, hlo)
         return compiled
+
+    def _compile_scan(self, K: int, pol, args, name: str):
+        """Build the K-round scan (under telemetry compiled ahead of time)
+        and cache it."""
+        fn = self._instrument_compile(
+            f"{name}[K={K}]", self._build_scan_fn(K, pol), args)
+        self._scan_cache[K] = fn
+        return fn
+
+    def _dispatch_scan(self, fn, args, K: int):
+        """``fn(*args)`` inside the ``round`` span.  Under telemetry the
+        span fences on the result: `mark` stamps the async-dispatch time,
+        the fence charges the span for the device compute it queued."""
+        with self._obs_span("round", mode="scanned", rounds=K) as sp:
+            out = fn(*args)
+            if self.obs is not None:
+                sp.mark("dispatch")
+                jax.block_until_ready(out)
+        return out
 
     def obs_state_summary(self) -> dict:
         """Host scalars for the telemetry gauges: Eqn-12 deficit-queue
@@ -480,159 +502,168 @@ class DeviceScaleEngine:
         task = self.task
         fm = self.faults
         twins = state.twins
-        # an active fault model splits one extra key; inert specs keep the
-        # exact pre-fault stream (and compile the exact pre-fault program —
-        # every fm.may_* gate below is a static Python bool)
-        if fm.active:
-            key, kb, ke, kc2, kdp, kflt = jax.random.split(state.key, 6)
-        else:
-            key, kb, ke, kc2, kdp = jax.random.split(state.key, 5)
-            kflt = None
-        if fm.may_drop:
-            # dropped members leave the padded mask AND become the padding
-            # sentinel, so every downstream gather fills neutrally and
-            # every scatter (reputation, twin observe) drops them — the
-            # round treats a dropped device exactly like a padding slot
-            mask = fm.drop_mask(kflt, mask, members)
-            members = jnp.where(mask, members, self._sentinel)
-        mask_f = mask.astype(jnp.float32)
-        cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
-        # a fully-dropped cluster skips its event: state carries unchanged
-        # (the degenerate all-padding aggregate would zero the cluster row)
-        empty = jnp.sum(mask_f) < 0.5 if fm.may_drop else None
+        with jax.named_scope("fl.batch"):
+            # an active fault model splits one extra key; inert specs keep
+            # the exact pre-fault stream (and compile the exact pre-fault
+            # program — every fm.may_* gate below is a static Python bool)
+            if fm.active:
+                key, kb, ke, kc2, kdp, kflt = jax.random.split(state.key, 6)
+            else:
+                key, kb, ke, kc2, kdp = jax.random.split(state.key, 5)
+                kflt = None
+            if fm.may_drop:
+                # dropped members leave the padded mask AND become the padding
+                # sentinel, so every downstream gather fills neutrally and
+                # every scatter (reputation, twin observe) drops them — the
+                # round treats a dropped device exactly like a padding slot
+                mask = fm.drop_mask(kflt, mask, members)
+                members = jnp.where(mask, members, self._sentinel)
+            mask_f = mask.astype(jnp.float32)
+            cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
+            # a fully-dropped cluster skips its event: state carries unchanged
+            # (the degenerate all-padding aggregate would zero the cluster row)
+            empty = jnp.sum(mask_f) < 0.5 if fm.may_drop else None
 
-        # --- controller choice capped by the Alg.-2 tolerance bound.
-        # T_m is the fastest cluster's time for the *requested* local phase
-        # (a_req / f_max, the convention test_tolerance_bound_caps_slow_
-        # clusters pins); slower clusters get proportionally fewer steps,
-        # scaling in as alpha grows.  The old reference (one step of the
-        # fastest cluster) made the cap floor to 1 for every cluster at
-        # alpha <= 1, silencing every frequency controller.
-        cluster_freq = self._cluster_freq_table(twins)
-        a_req = jnp.clip(jnp.asarray(a_raw), 1, self._n_actions)
-        t_ref = a_req.astype(jnp.float32) / jnp.maximum(
-            jnp.max(cluster_freq), 1e-6)
-        alpha = jnp.minimum(
-            1.0, spec.clustering.alpha0 +
-            spec.clustering.alpha_growth * state.round.astype(jnp.float32))
-        a = tolerance_bound(a_req, cluster_freq[c], t_ref, alpha)
-        a = jnp.clip(a, 1, self._n_actions)
+            # --- controller choice capped by the Alg.-2 tolerance bound.
+            # T_m is the fastest cluster's time for the *requested* local phase
+            # (a_req / f_max, the convention test_tolerance_bound_caps_slow_
+            # clusters pins); slower clusters get proportionally fewer steps,
+            # scaling in as alpha grows.  The old reference (one step of the
+            # fastest cluster) made the cap floor to 1 for every cluster at
+            # alpha <= 1, silencing every frequency controller.
+            cluster_freq = self._cluster_freq_table(twins)
+            a_req = jnp.clip(jnp.asarray(a_raw), 1, self._n_actions)
+            t_ref = a_req.astype(jnp.float32) / jnp.maximum(
+                jnp.max(cluster_freq), 1e-6)
+            alpha = jnp.minimum(
+                1.0, spec.clustering.alpha0 +
+                spec.clustering.alpha_growth * state.round.astype(jnp.float32))
+            a = tolerance_bound(a_req, cluster_freq[c], t_ref, alpha)
+            a = jnp.clip(a, 1, self._n_actions)
 
-        # --- local batches from the padded partition matrix
-        sel = sample_member_batch(kb, self._part_idx, self._part_len,
-                                  members, spec.local_batch)
-        x = self._x[sel]
-        y = self._y[sel]
-        if fm.may_poison:
-            # poisons the sampled features before they enter local_train;
-            # for reconstruction tasks (corrupt_labels a no-op) this is the
-            # only attack surface that touches the loss
-            x = fm.poison_inputs(kflt, x, members)
-        mal_m = self._malicious_dev.at[members].get(mode="fill",
-                                                    fill_value=0.0)
-        y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
-        batch = {"x": x, "y": y}
+            # --- local batches from the padded partition matrix
+            sel = sample_member_batch(kb, self._part_idx, self._part_len,
+                                      members, spec.local_batch)
+            x = self._x[sel]
+            y = self._y[sel]
+            if fm.may_poison:
+                # poisons the sampled features before they enter local_train;
+                # for reconstruction tasks (corrupt_labels a no-op) this is the
+                # only attack surface that touches the loss
+                x = fm.poison_inputs(kflt, x, members)
+            mal_m = self._malicious_dev.at[members].get(mode="fill",
+                                                        fill_value=0.0)
+            y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
+            batch = {"x": x, "y": y}
 
-        # --- a local steps on every member (vmap), from the cluster model
-        m_dim = members.shape[0]
-        stacked = jax.tree.map(
-            lambda l: jnp.broadcast_to(l[c], (m_dim,) + l.shape[1:]),
-            state.cluster_params)
-        new = task.local_train(stacked, batch, spec.lr, a)
-        if fm.may_corrupt:
-            # Byzantine members replace their honest deltas *before* the
-            # trust chain sees them — Eqns 4-5 must earn their keep
-            new = fm.corrupt_updates(kflt, new, stacked, members)
+        with jax.named_scope("fl.local_sgd"):
+            # --- a local steps on every member (vmap), from the cluster model
+            m_dim = members.shape[0]
+            stacked = jax.tree.map(
+                lambda l: jnp.broadcast_to(l[c], (m_dim,) + l.shape[1:]),
+                state.cluster_params)
+            new = task.local_train(stacked, batch, spec.lr, a)
+            if fm.may_corrupt:
+                # Byzantine members replace their honest deltas *before* the
+                # trust chain sees them — Eqns 4-5 must earn their keep
+                new = fm.corrupt_updates(kflt, new, stacked, members)
 
         # --- trust update (Eqns 4-5) & pluggable aggregation (Eqn 6)
-        upd_flat = _flatten_params(new) - _flatten_params(stacked)
-        q = learning_quality(upd_flat, mask)
-        div = gradient_diversity(upd_flat, mask)
-        tw_m = member_view(twins, members)
-        if fm.may_spike:
-            # amplified f̂ deviation feeds straight into Eqn 4's
-            # 1/(1+|Δf̂|) normalization
-            tw_m = fm.spike_twins(kflt, tw_m, mask, members)
-        b = belief(tw_m, q, spec.channel.pkt_fail, div)
-        rep_m = update_reputation(
-            state.rep.at[members].get(mode="fill", fill_value=1.0), b,
-            spec.channel.pkt_fail, spec.iota)
-        rep = state.rep.at[members].set(rep_m, mode="drop")
-        w = trust_weights(rep_m, mask)
-        # with a fused-global aggregator the Eqn-6 aggregate never leaves
-        # the kernel (see the Eqn-19 block below); DP needs the bare
-        # aggregate to clip against, so it keeps the two-step path
-        fuse_global = self._fused_global and spec.privacy.clip <= 0.0
-        if not fuse_global:
-            agg = (self.aggregator(new, w, mask) if self._padded
-                   else self.aggregator(new, w))
-            if spec.privacy.clip > 0.0:
-                from repro.core.privacy import dp_aggregate
-                cur = jax.tree.map(lambda l: l[c], state.cluster_params)
-                agg = dp_aggregate(
-                    kdp, new, cur,
-                    w if spec.aggregator.kind == "trust" else mask_f / cnt,
-                    spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
+        with jax.named_scope("fl.flatten"):
+            upd_flat = _flatten_params(new) - _flatten_params(stacked)
+        with jax.named_scope("fl.trust"):
+            q = learning_quality(upd_flat, mask)
+            div = gradient_diversity(upd_flat, mask)
+            tw_m = member_view(twins, members)
+            if fm.may_spike:
+                # amplified f̂ deviation feeds straight into Eqn 4's
+                # 1/(1+|Δf̂|) normalization
+                tw_m = fm.spike_twins(kflt, tw_m, mask, members)
+            b = belief(tw_m, q, spec.channel.pkt_fail, div)
+            rep_m = update_reputation(
+                state.rep.at[members].get(mode="fill", fill_value=1.0), b,
+                spec.channel.pkt_fail, spec.iota)
+            rep = state.rep.at[members].set(rep_m, mode="drop")
+            w = trust_weights(rep_m, mask)
+        with jax.named_scope("fl.aggregate"):
+            # with a fused-global aggregator the Eqn-6 aggregate never leaves
+            # the kernel (see the Eqn-19 block below); DP needs the bare
+            # aggregate to clip against, so it keeps the two-step path
+            fuse_global = self._fused_global and spec.privacy.clip <= 0.0
+            if not fuse_global:
+                agg = (self.aggregator(new, w, mask) if self._padded
+                       else self.aggregator(new, w))
+                if spec.privacy.clip > 0.0:
+                    from repro.core.privacy import dp_aggregate
+                    cur = jax.tree.map(lambda l: l[c], state.cluster_params)
+                    agg = dp_aggregate(
+                        kdp, new, cur,
+                        w if spec.aggregator.kind == "trust" else mask_f / cnt,
+                        spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
+                cparams = jax.tree.map(
+                    lambda L, g: L.at[c].set(g.astype(L.dtype)),
+                    state.cluster_params, agg)
+
+        with jax.named_scope("fl.twins"):
+            # --- losses, energy (Eqns 7-8), twins
+            losses = task.losses(new, batch)
+            true_freq = (twins.freq + twins.freq_dev).at[members].get(
+                mode="fill", fill_value=1.0)
+            ch_m = state.channel.at[members].get(mode="fill", fill_value=0)
+            e = round_energy(a.astype(jnp.float32), true_freq, ch_m, ke,
+                             members=members) * mask_f
+            consumed = jnp.sum(e)
+            twins = observe_round_members(twins, members, losses, e,
+                                          self._misbehaving_dev)
+            if spec.fleet.calibrate_dt:
+                twins = calibrate(twins)
+            channel = step_channel(kc2, state.channel, self._trans)
+
+        with jax.named_scope("fl.aggregate"):
+            # --- Eqn 19: staleness-weighted global aggregate (async pull)
+            rnd = state.round + 1
+            ts = state.cluster_ts.at[c].set(rnd.astype(jnp.float32))
+            if fuse_global:
+                # one kernel pass: Eqn-6 reduction of the member updates +
+                # substitution into the cluster stack + the Eqn-19 average
+                # ((n_clusters + C, block) tiles per grid step; the per-shard
+                # unit under a mesh placement)
+                gparams = self.aggregator.aggregate_with_global(
+                    new, w, mask, state.cluster_params,
+                    staleness_weights(rnd.astype(jnp.float32) - ts), c)
+                cparams = state.cluster_params
+            else:
+                gparams, _ = time_weighted_average(
+                    cparams, rnd.astype(jnp.float32) - ts)
             cparams = jax.tree.map(
-                lambda L, g: L.at[c].set(g.astype(L.dtype)),
-                state.cluster_params, agg)
+                lambda L, g: L.at[c].set(g.astype(L.dtype)), cparams,
+                gparams)
 
-        # --- losses, energy (Eqns 7-8), twins
-        losses = task.losses(new, batch)
-        true_freq = (twins.freq + twins.freq_dev).at[members].get(
-            mode="fill", fill_value=1.0)
-        ch_m = state.channel.at[members].get(mode="fill", fill_value=0)
-        e = round_energy(a.astype(jnp.float32), true_freq, ch_m, ke,
-                         members=members) * mask_f
-        consumed = jnp.sum(e)
-        twins = observe_round_members(twins, members, losses, e,
-                                      self._misbehaving_dev)
-        if spec.fleet.calibrate_dt:
-            twins = calibrate(twins)
-        channel = step_channel(kc2, state.channel, self._trans)
+        with jax.named_scope("fl.twins"):
+            if fm.may_drop:
+                # graceful degradation: a fully-dropped cluster spends nothing
+                # and leaves every model/trust/twin leaf untouched — only the
+                # RNG key, channel, and round counter advance, so the scheduler
+                # re-enqueues the cluster instead of writing a zeroed aggregate
+                revert = lambda old, newv: jax.tree.map(
+                    lambda o, v: jnp.where(empty, o, v), old, newv)
+                consumed = jnp.where(empty, 0.0, consumed)
+                twins = revert(state.twins, twins)
+                rep = revert(state.rep, rep)
+                cparams = revert(state.cluster_params, cparams)
+                gparams = revert(state.global_params, gparams)
+                ts = revert(state.cluster_ts, ts)
 
-        # --- Eqn 19: staleness-weighted global aggregate (async pull)
-        rnd = state.round + 1
-        ts = state.cluster_ts.at[c].set(rnd.astype(jnp.float32))
-        if fuse_global:
-            # one kernel pass: Eqn-6 reduction of the member updates +
-            # substitution into the cluster stack + the Eqn-19 average
-            # ((n_clusters + C, block) tiles per grid step; the per-shard
-            # unit under a mesh placement)
-            gparams = self.aggregator.aggregate_with_global(
-                new, w, mask, state.cluster_params,
-                staleness_weights(rnd.astype(jnp.float32) - ts), c)
-            cparams = state.cluster_params
-        else:
-            gparams, _ = time_weighted_average(cparams,
-                                               rnd.astype(jnp.float32) - ts)
-        cparams = jax.tree.map(lambda L, g: L.at[c].set(g.astype(L.dtype)),
-                               cparams, gparams)
+            # --- Eqn 12: the deficit queue advances in-jit with the realized
+            # consumption (budgetless controllers have per_slot=inf -> q = 0)
+            queue = ctl_queue.advance(state.queue, consumed,
+                                      self._queue_per_slot)
 
-        if fm.may_drop:
-            # graceful degradation: a fully-dropped cluster spends nothing
-            # and leaves every model/trust/twin leaf untouched — only the
-            # RNG key, channel, and round counter advance, so the scheduler
-            # re-enqueues the cluster instead of writing a zeroed aggregate
-            revert = lambda old, newv: jax.tree.map(
-                lambda o, v: jnp.where(empty, o, v), old, newv)
-            consumed = jnp.where(empty, 0.0, consumed)
-            twins = revert(state.twins, twins)
-            rep = revert(state.rep, rep)
-            cparams = revert(state.cluster_params, cparams)
-            gparams = revert(state.global_params, gparams)
-            ts = revert(state.cluster_ts, ts)
-
-        # --- Eqn 12: the deficit queue advances in-jit with the realized
-        # consumption (budgetless controllers have per_slot=inf -> q = 0)
-        queue = ctl_queue.advance(state.queue, consumed,
-                                  self._queue_per_slot)
-
-        # --- round duration from the *post-calibration* straggler freq
-        dur = a.astype(jnp.float32) / jnp.maximum(
-            self._cluster_freq_table(twins)[c], 1e-6)
-        if fm.may_straggle:
-            dur = fm.straggle(kflt, dur, mask, members)
+            # --- round duration from the *post-calibration* straggler freq
+            dur = a.astype(jnp.float32) / jnp.maximum(
+                self._cluster_freq_table(twins)[c], 1e-6)
+            if fm.may_straggle:
+                dur = fm.straggle(kflt, dur, mask, members)
 
         new_state = FleetState(
             twins=twins, rep=rep, channel=channel, cluster_params=cparams,
@@ -729,18 +760,19 @@ class DeviceScaleEngine:
             # first (lowest) cluster index exactly as tuple order does
             c = jnp.argmin(times).astype(jnp.int32)
             t = times[c]
-            feats = self._ctl_features(state, c)
-            obs48 = (self._scan_obs(state, c, feats)
-                     if pol.needs_obs else jnp.zeros((OBS_DIM,),
-                                                     jnp.float32))
-            cobs = ctl_policy.CtlObs(
-                round=state.round, cluster=c, queue=state.queue,
-                cluster_loss=feats["cluster_loss"],
-                cluster_freq=feats["cluster_freq"],
-                mean_freq=feats["mean_freq"],
-                channel_good_frac=feats["channel_good_frac"],
-                energy_used=energy, dqn_obs=obs48)
-            a_raw, ctl = pol.step(ctl, cobs)
+            with jax.named_scope("fl.control"):
+                feats = self._ctl_features(state, c)
+                obs48 = (self._scan_obs(state, c, feats)
+                         if pol.needs_obs else jnp.zeros((OBS_DIM,),
+                                                         jnp.float32))
+                cobs = ctl_policy.CtlObs(
+                    round=state.round, cluster=c, queue=state.queue,
+                    cluster_loss=feats["cluster_loss"],
+                    cluster_freq=feats["cluster_freq"],
+                    mean_freq=feats["mean_freq"],
+                    channel_good_frac=feats["channel_good_frac"],
+                    energy_used=energy, dqn_obs=obs48)
+                a_raw, ctl = pol.step(ctl, cobs)
             state, m = self._fleet_round(
                 state, c, a_raw, self._member_table[c],
                 self._member_mask[c])
@@ -801,25 +833,15 @@ class DeviceScaleEngine:
             raise ValueError(
                 f"controller {type(self.controller).__name__} has no "
                 "scan_policy(); use the event-heap run() instead")
-        pol = scan_policy()
         K = int(K)
-        args = (self.state, self._scan_times, pol.state,
-                self._scan_energy_start())
-        fn = self._scan_cache.get(K)
+        with self._obs_span("prepare"):
+            pol = scan_policy()
+            args = (self.state, self._scan_times, pol.state,
+                    self._scan_energy_start())
+            fn = self._scan_cache.get(K)
         if fn is None:
-            fn = self._instrument_compile(
-                f"run_scanned[K={K}]", self._build_scan_fn(K, pol), args)
-            self._scan_cache[K] = fn
-        if self.obs is None:
-            out = fn(*args)
-        else:
-            # fenced round span: `mark` stamps the async-dispatch time,
-            # the fence charges the span for the device compute it queued
-            with self.obs.span("round", mode="scanned", rounds=K) as sp:
-                out = fn(*args)
-                sp.mark("dispatch")
-                jax.block_until_ready(out)
-        (state, times, _, energy_end), ys = out
+            fn = self._compile_scan(K, pol, args, "run_scanned")
+        (state, times, _, energy_end), ys = self._dispatch_scan(fn, args, K)
         self.state = state
         self._scan_times = times        # schedule carries to the next call
         return self._emit_scanned_trace(ys, K, eval_final, energy_end)
@@ -872,27 +894,30 @@ class DeviceScaleEngine:
                 self.obs.m_rounds.inc(K)
             return self._new_trace()
 
-        self._flush_pending()
+        with self._obs_span("flush"):
+            self._flush_pending()
         with self._obs_span("host_sync", rounds=K):
             ys = jax.device_get(ys)         # the one end-of-run sync
-        # rebuild the float64 tally by the same sequential additions the
-        # event loop performs (bitwise-identical cumulative energies)
-        cum = []
-        for ci in np.asarray(ys["consumed"], np.float32):
-            self._energy_used += float(ci)
-            cum.append(self._energy_used)
-        if sync_queue is not None:          # host controller adopts the
-            sync_queue(self.state.queue)    # device-resident backlog
-        if self.obs is not None:
-            self.obs.on_segment(ys, K, engine=self)
+        with self._obs_span("records"):
+            # rebuild the float64 tally by the same sequential additions
+            # the event loop performs (bitwise-identical cumulative
+            # energies)
+            cum = []
+            for ci in np.asarray(ys["consumed"], np.float32):
+                self._energy_used += float(ci)
+                cum.append(self._energy_used)
+            if sync_queue is not None:      # host controller adopts the
+                sync_queue(self.state.queue)  # device-resident backlog
+            if self.obs is not None:
+                self.obs.on_segment(ys, K, engine=self)
 
-        trace = self._new_trace()
-        for i in range(K):
-            trace.append(RoundRecord(
-                t=float(ys["t"][i]), round=base + i + 1,
-                cluster=int(ys["cluster"][i]), a=int(ys["a"][i]),
-                loss=float(ys["loss"][i]), acc=None, energy=cum[i],
-                agg_count=base + i + 1))
+            trace = self._new_trace()
+            for i in range(K):
+                trace.append(RoundRecord(
+                    t=float(ys["t"][i]), round=base + i + 1,
+                    cluster=int(ys["cluster"][i]), a=int(ys["a"][i]),
+                    loss=float(ys["loss"][i]), acc=None, energy=cum[i],
+                    agg_count=base + i + 1))
         if eval_final:
             with self._obs_span("eval"):
                 ev = self.task.evaluate(self.state.global_params,

@@ -28,6 +28,10 @@ member updates of the round's cluster (Eqn 6) *and* substitutes the result
 into the (B, block) stacked-cluster tile for the Eqn-19 staleness-weighted
 global average — one VMEM pass instead of kernel + jnp re-read, and the
 unit the placement layer partitions per shard.
+
+Each ``pallas_call`` carries a fixed ``name`` (``trust_aggregate``,
+``trust_aggregate_masked``, ``trust_aggregate_global``), which the chip's
+profiler trace names the kernel's operation by.
 """
 from __future__ import annotations
 
@@ -104,12 +108,14 @@ def trust_aggregate(params_flat, weights, mask=None, *,
         out = pl.pallas_call(
             _kernel, grid=grid, in_specs=[w_spec, x_spec],
             out_specs=out_spec, out_shape=out_shape, interpret=interpret,
+            name="trust_aggregate",
         )(weights[:, None], x)
     else:
         out = pl.pallas_call(
             _masked_kernel, grid=grid,
             in_specs=[w_spec, pl.BlockSpec((C, 1), lambda i: (0, 0)), x_spec],
             out_specs=out_spec, out_shape=out_shape, interpret=interpret,
+            name="trust_aggregate_masked",
         )(weights[:, None], mask.astype(jnp.float32)[:, None], x)
     return out[0, :N]
 
@@ -144,7 +150,7 @@ def trust_aggregate_global(updates_flat, weights, mask, stack_flat,
                   pl.BlockSpec((B, block), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Np), stack_flat.dtype),
-        interpret=interpret,
+        interpret=interpret, name="trust_aggregate_global",
     )(jnp.asarray(c, jnp.int32).reshape(1, 1), weights[:, None],
       mask.astype(jnp.float32)[:, None], global_weights[:, None],
       updates_flat, stack_flat)

@@ -17,6 +17,9 @@ single JSONL sink (the run dir's ``metrics.jsonl``).  Attach it with
   executable the jit path would build), times it under a
   ``span("compile")``, and feeds the optimized HLO through
   `repro.launch.hlo_stats.analyze_module` for collective counts;
+* every compile of the process, explicit or implicit: the
+  `repro.obs.compiles` listener feeds ``fl_compiles_total`` and
+  ``fl_compile_seconds_total`` of each live bundle;
 * fault bookkeeping: the `FaultModel`'s *static* tallies (Byzantine
   subset sizes, per-family rates) as gauges, plus a rounds-under-fault
   counter.  Realized in-jit draws are deliberately not counted — that
@@ -34,6 +37,7 @@ from typing import Optional
 
 import numpy as np
 
+from . import compiles
 from .metrics import MetricsRegistry, snapshot_record
 from .spans import SpanRecorder
 
@@ -79,7 +83,8 @@ class EngineObs:
         self.m_beta = r.gauge(
             "fl_twin_beta_sum", "total Eqn-4 negative-interaction tally")
         self.m_compiles = r.counter(
-            "fl_compiles_total", "device programs compiled")
+            "fl_compiles_total",
+            "backend compiles (persistent-cache loads included)")
         self.m_compile_s = r.counter(
             "fl_compile_seconds_total", "wall seconds spent compiling")
         self.m_hlo_coll = r.gauge(
@@ -95,6 +100,8 @@ class EngineObs:
             "fl_checkpoint_bytes", "size of the last checkpoint")
         self.m_fault_rounds = r.counter(
             "fl_fault_rounds_total", "rounds run under an active FaultSpec")
+        compiles.install()
+        compiles.COUNTER.subscribe(self)
 
     # ------------------------------------------------------------------ #
     def span(self, name: str, fence_on=None, **attrs):
@@ -184,10 +191,9 @@ class EngineObs:
 
     def record_compile(self, fn_name: str, seconds: float,
                        hlo_text: Optional[str] = None) -> None:
-        """One-time compile event: counters + HLO collective stats + an
-        ``event/1`` record in metrics.jsonl."""
-        self.m_compiles.inc(1, fn=fn_name)
-        self.m_compile_s.inc(float(seconds), fn=fn_name)
+        """One-time compile event: HLO collective stats + an ``event/1``
+        record in metrics.jsonl (the compile counters are fed by
+        `repro.obs.compiles`, which sees this compile too)."""
         event = {"schema": EVENT_SCHEMA, "event": "compile",
                  "ts": time.time(), "fn": fn_name,
                  "seconds": float(seconds)}

@@ -8,11 +8,14 @@ here pins the registry semantics (cardinality guard, Prometheus text
 golden, snapshot round-trip), the span tree machinery, and the JSONL
 crash hardening (torn final lines, sink reopen after rotation).
 """
+import contextlib
 import dataclasses
+import glob
 import json
 import os
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 import repro.api as api
@@ -22,7 +25,7 @@ from repro.api.records import (JsonlSink, RoundRecord, read_jsonl_trace,
                                tail_jsonl)
 from repro.data import dirichlet_partition, make_classification
 from repro.obs import (METRICS_SCHEMA, SPAN_SCHEMA, EngineObs,
-                       MetricsRegistry, SpanRecorder,
+                       MetricsRegistry, SpanRecorder, compiles,
                        merge_snapshot_records, snapshot_record)
 
 
@@ -268,7 +271,10 @@ def test_scanned_trace_bit_parity_with_obs(tmp_path):
         assert a == b                     # dataclass eq: floats exact
     totals = obs.registry.totals()
     assert totals["fl_rounds_total"] == 6
-    assert totals["fl_compiles_total"] == 1
+    # the compile counter sees every compile of the process; the scan's
+    # program among them, once
+    assert obs.registry.get("fl_compiles_total").value(
+        fn="jit(run_k)") == 1
     assert totals["fl_sim_seconds_total"] > 0
     assert obs.spans.last("round") is not None
     assert obs.spans.last("compile") is not None
@@ -314,6 +320,115 @@ def test_state_summary_is_read_only():
     del before, after                     # summary ran between segments
     # and calling it again mid-stream gives the same numbers (pure read)
     assert fed.engine.obs_state_summary() == fed.engine.obs_state_summary()
+
+
+# --------------------------------------------------------------------- #
+# the engine on the profiler's clock: host spans, compiles, device stages
+# --------------------------------------------------------------------- #
+SEGMENT_SPANS = ["prepare", "round", "flush", "host_sync", "records", "eval"]
+ROUND_STAGES = ("fl.control", "fl.batch", "fl.local_sgd", "fl.flatten",
+                "fl.trust", "fl.aggregate", "fl.twins")
+
+
+def _host_events(trace_dir, prefixes):
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    pd = ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.end_ns)
+                   for p in pd.planes if p.name.startswith("/host:")
+                   for line in p.lines for e in line.events
+                   if e.name.startswith(prefixes)), key=lambda e: e[1])
+
+
+def test_segment_spans_reach_the_profiler_without_obs(tmp_path):
+    data, parts = _data(seed=8)
+    fed = Federation.from_spec(_spec(seed=8), data=data, parts=parts)
+    fed.engine.run_scanned(4)               # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.segment"):
+            fed.engine.run_scanned(4)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path, ("fl.", "test."))
+    (_, t0, t1), = [e for e in events if e[0] == "test.segment"]
+    inside = [n for n, a, b in events
+              if n.startswith("fl.") and t0 <= a and b <= t1]
+    assert inside == ["fl." + n for n in SEGMENT_SPANS]
+
+    # with a recorder attached, its spans carry the same names
+    obs = EngineObs()
+    fed.engine.set_obs(obs)
+    fed.engine.run_scanned(4)
+    assert [sp.name for sp in obs.spans.finished] == SEGMENT_SPANS
+
+
+def test_recorder_spans_are_profiler_annotations(tmp_path):
+    rec = SpanRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("segment"):
+            with rec.span("checkpoint"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    names = [n for n, _, _ in _host_events(tmp_path, ("fl.",))]
+    assert names == ["fl.segment", "fl.checkpoint"]
+
+
+def test_compile_counter_counts_a_new_shape_once():
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    small, big = jnp.ones(3), jnp.ones(5)
+    obs = EngineObs()                       # fed while it lives
+    before = compiles.counts()
+    f(small)
+    first = compiles.counts()
+    f(small)
+    repeat = compiles.counts()
+    f(big)
+    reshaped = compiles.counts()
+    assert first["lowerings"] - before["lowerings"] == 1
+    assert first["compiles"] - before["compiles"] == 1
+    assert repeat == first
+    assert reshaped["lowerings"] - repeat["lowerings"] == 1
+    counter = obs.registry.get("fl_compiles_total")
+    assert counter.value(fn="jit(<lambda>)") == 2
+    assert obs.registry.get("fl_compile_seconds_total").total() > 0
+
+
+def test_compiled_scan_carries_each_stage_scope():
+    data, parts = _data(seed=9)
+    # a controller that scores the features (a fixed `a` leaves no
+    # control ops in the optimized program)
+    spec = dataclasses.replace(_spec(seed=9), controller=ControllerSpec(
+        "lyapunov", {"budget": 200.0, "horizon": 40}))
+    fed = Federation.from_spec(spec, data=data, parts=parts)
+    obs = EngineObs()                       # compiles ahead of time
+    fed.engine.set_obs(obs)
+    fed.engine.run_scanned(3, eval_final=False)
+    hlo = fed.engine._scan_cache[3].as_text()
+    for name in ROUND_STAGES:
+        assert f"/{name}/" in hlo, name
+
+
+def test_stage_scopes_leave_the_trace_bit_identical(monkeypatch):
+    data, parts = _data(seed=10)
+
+    def run():
+        fed = Federation.from_spec(_spec(seed=10), data=data, parts=parts)
+        fed.engine.set_obs(EngineObs())
+        records = fed.engine.run_scanned(6).records
+        return records, fed.engine._scan_cache[6].as_text()
+
+    want, scoped_hlo = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    got, plain_hlo = run()
+    assert "/fl.trust/" in scoped_hlo and "/fl.trust/" not in plain_hlo
+    assert len(got) == len(want) == 7
+    for a, b in zip(want, got):
+        assert a == b                     # dataclass eq: floats exact
 
 
 # --------------------------------------------------------------------- #
