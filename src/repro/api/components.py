@@ -48,8 +48,8 @@ from repro.core.autoencoder import (anomaly_auc, code_mean,
                                     reconstruction_errors,
                                     reconstruction_loss)
 from repro.core.lyapunov import init_queue, step_queue
-from repro.core.mlp import (accuracy, classifier_loss, init_mlp_classifier,
-                            mlp_hidden_mean)
+from repro.core.mlp import (classifier_loss, evaluate_classifier,
+                            init_mlp_classifier, mlp_hidden_mean)
 from repro.core.robust import AGGREGATORS as ROBUST_RULES
 from repro.core.robust import MASKED_AGGREGATORS as MASKED_RULES
 from repro.core.trust import trust_weighted_average
@@ -367,11 +367,10 @@ class MLPTask:
         return classifier_loss(params, batch)
 
     def evaluate(self, params, data) -> Dict[str, float]:
-        return {
-            "acc": float(accuracy(params, data.x, data.y)),
-            "loss": float(classifier_loss(
-                params, {"x": data.x[:1024], "y": data.y[:1024]})),
-        }
+        # one program, one fetch: the scanned engine calls this every segment
+        acc, loss = jax.device_get(evaluate_classifier(params, data.x,
+                                                       data.y)).tolist()
+        return {"acc": acc, "loss": loss}
 
     def hidden_mean(self, params, x):
         return mlp_hidden_mean(params, x)

@@ -23,6 +23,7 @@ from repro.api import (AggregatorSpec, ControllerSpec, Federation,
                        FederationSpec, FleetSpec, TaskSpec)
 from repro.api.records import (JsonlSink, RoundRecord, read_jsonl_trace,
                                tail_jsonl)
+from repro.core import mlp
 from repro.data import dirichlet_partition, make_classification
 from repro.obs import (METRICS_SCHEMA, SPAN_SCHEMA, EngineObs,
                        MetricsRegistry, SpanRecorder, compiles,
@@ -395,6 +396,32 @@ def test_compile_counter_counts_a_new_shape_once():
     counter = obs.registry.get("fl_compiles_total")
     assert counter.value(fn="jit(<lambda>)") == 2
     assert obs.registry.get("fl_compile_seconds_total").total() > 0
+
+
+@pytest.mark.parametrize("n,dim", [(1536, 48), (640, 32)])
+def test_compiled_evaluation_matches_the_eager_formula(n, dim):
+    data, parts = _data(n=n, dim=dim, seed=11)
+    fed = Federation.from_spec(_spec(seed=11), data=data, parts=parts)
+    final = fed.engine.run_scanned(4, eval_final=True).records[-1]
+    params = fed.engine.state.global_params
+    acc = float(mlp.accuracy(params, data.x, data.y))
+    loss = float(mlp.classifier_loss(
+        params, {"x": data.x[:1024], "y": data.y[:1024]}))
+
+    ev = fed.engine.task.evaluate(params, data)
+    before = compiles.counts()
+    again = fed.engine.task.evaluate(params, data)
+    assert compiles.counts()["lowerings"] == before["lowerings"]
+    assert ev == again
+    assert ev["acc"] == acc and final.acc == acc
+    assert ev["loss"] == pytest.approx(loss, rel=1e-6)
+    assert final.loss == ev["loss"]
+
+    # a shape not seen yet costs one lowering: one program, not op by op
+    cut = data._replace(x=data.x[:n - 1], y=data.y[:n - 1])
+    before = compiles.counts()
+    fed.engine.task.evaluate(params, cut)
+    assert compiles.counts()["lowerings"] == before["lowerings"] + 1
 
 
 def test_compiled_scan_carries_each_stage_scope():
