@@ -71,9 +71,10 @@ def timed_scan(engine, K: int):
     hlo = []
 
     class Obs(EngineObs):
-        def record_compile(self, fn_name, seconds, hlo_text=None):
+        def record_compile(self, fn_name, seconds, hlo_text=None,
+                           **kw):
             hlo.append(hlo_text or "")
-            super().record_compile(fn_name, seconds, hlo_text)
+            super().record_compile(fn_name, seconds, hlo_text, **kw)
 
     obs = Obs()
     engine.set_obs(obs)

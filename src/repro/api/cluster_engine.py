@@ -131,7 +131,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
         self.placement = shard_map_placement(spec.sharding)
         self._ax = spec.sharding.resolved_axes()[0]
         G = int(spec.sharding.mesh[0])
-        S = int(self._member_table.shape[1])
+        S = int(self.tables.member_table.shape[1])
         C_pad = -(-C // G) * G          # auto-pad: masked sentinel clusters
         n_pad = C_pad * S               # ... and sentinel device slots
         self._n, self._C, self._S, self._G = n, C, S, G
@@ -150,7 +150,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
         # slot -> original device id (sentinel n at padding) and its
         # stable inverse; member_table rows are ascending original ids
         oos = np.full((n_pad,), n, np.int32)
-        oos[:C * S] = np.asarray(self._member_table).reshape(-1)
+        oos[:C * S] = np.asarray(self.tables.member_table).reshape(-1)
         real = oos < n
         soo = np.zeros((n,), np.int32)
         soo[oos[real]] = np.nonzero(real)[0].astype(np.int32)
@@ -164,17 +164,21 @@ class ClusterMajorEngine(DeviceScaleEngine):
                 "weight 0, sentinel device slots are masked everywhere",
                 C, C_pad, n, n_pad, tuple(spec.sharding.mesh), S)
 
-        # permute the freshly built state cluster-major and commit it (and
-        # the per-shard static tables) to the mesh
+        # permute the freshly built state cluster-major and commit it, the
+        # per-shard static tables and the replicated fleet tables to the
+        # mesh; every compiled entry point takes `_statics` as arguments
         self.state = self._shard_cm(self._permute_state(self.state))
         dev = NamedSharding(self.placement.mesh, P(self._ax))
+        repl = NamedSharding(self.placement.mesh, P())
+        self.tables = jax.tree.map(lambda v: self._commit(v, repl),
+                                   self.tables)
         self._statics = tuple(self._commit(v, dev) for v in (
             self._oos,
-            self._misbehaving_dev.at[self._oos].get(mode="fill",
-                                                    fill_value=0.0),
+            self.tables.misbehaving.at[self._oos].get(mode="fill",
+                                                      fill_value=0.0),
             jnp.asarray(real),                   # slot validity (n_pad,)
             jnp.asarray(np.arange(C_pad) < C),   # cluster validity (C_pad,)
-        ))
+        )) + (self.tables,)
         self._scan_times = jnp.concatenate([
             jnp.zeros((C,), jnp.float32),
             jnp.full((C_pad - C,), jnp.inf, jnp.float32)])
@@ -187,16 +191,17 @@ class ClusterMajorEngine(DeviceScaleEngine):
         self._gp_sizes = [int(np.prod(l.shape)) if l.shape else 1
                           for l in gleaves]
         self._gp_dtypes = [l.dtype for l in gleaves]
-        self._x256 = self._x[:256]
 
         # swap the execution paths in for the parent's jits
         self._event_fn = None
         self._round_fn = self._cm_event_round
         self._scan_cache = {}
+        # (the parent's ``tables`` argument is `self.tables`, which rides
+        # in `_statics`)
         self._feo_fn = self._build_feats_fn()
-        self._features_fn = lambda state, c: self._feo_fn(
+        self._features_fn = lambda state, tables, c: self._feo_fn(
             state, self._ftbl, self._ch3, c, *self._statics)[0]
-        self._obs_fn = lambda state, c: self._feo_fn(
+        self._obs_fn = lambda state, tables, c: self._feo_fn(
             state, self._ftbl, self._ch3, c, *self._statics)[1]
         self._aux_fn = self._build_aux_fn()
         # carried replicated per-round aggregates: the (C_pad,) straggler
@@ -298,7 +303,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
     # the per-shard round (traced under shard_map)
     # ------------------------------------------------------------------ #
     def _cm_round_local(self, state, ftbl, ch3, c, a_raw,
-                        oos_l, misb_l, mskslot_l, validc_l):
+                        oos_l, misb_l, mskslot_l, validc_l, tables):
         """One cluster round, shard-local: the parent `_fleet_round` split
         into replicated pre-work, an owner-gated member phase, and two
         psums.  Returns (state', ftbl', ch3', metrics)."""
@@ -334,27 +339,27 @@ class ClusterMajorEngine(DeviceScaleEngine):
             return jnp.where(mask, sl, fill)
 
         # --- owner phase: the parent's member round, verbatim math.  The
-        # full-fleet static tables (member/partition/data/fault) ride in as
-        # replicated closure constants, so gathers by *original* id are
-        # identical to the parent's; only sharded FleetState leaves read
-        # through dynamic_slice at the cluster's slot block.
+        # full-fleet `FleetTables` (member/partition/data/attackers) ride
+        # in replicated, so gathers by *original* id are identical to the
+        # parent's; only sharded FleetState leaves read through
+        # dynamic_slice at the cluster's slot block.
         def owner(_):
-            members = self._member_table[c]
-            mask = self._member_mask[c]
+            members = tables.member_table[c]
+            mask = tables.member_mask[c]
             if fm.may_drop:
                 mask = fm.drop_mask(kflt, mask, members)
                 members = jnp.where(mask, members, self._sentinel)
             mask_f = mask.astype(jnp.float32)
             cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
 
-            sel = sample_member_batch(kb, self._part_idx, self._part_len,
+            sel = sample_member_batch(kb, tables.part_idx, tables.part_len,
                                       members, spec.local_batch)
-            x = self._x[sel]
-            y = self._y[sel]
+            x = tables.x[sel]
+            y = tables.y[sel]
             if fm.may_poison:
                 x = fm.poison_inputs(kflt, x, members)
-            mal_m = self._malicious_dev.at[members].get(mode="fill",
-                                                        fill_value=0.0)
+            mal_m = tables.malicious.at[members].get(mode="fill",
+                                                     fill_value=0.0)
             y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
             batch = {"x": x, "y": y}
 
@@ -527,7 +532,8 @@ class ClusterMajorEngine(DeviceScaleEngine):
     # ------------------------------------------------------------------ #
     # controller features / observation, shard-local
     # ------------------------------------------------------------------ #
-    def _cm_feats_local(self, state, ftbl, ch3, c, mskslot_l, needs_obs):
+    def _cm_feats_local(self, state, ftbl, ch3, c, mskslot_l, needs_obs,
+                        tables):
         """Parent `_ctl_features` + `_scan_obs` over the owner's slot
         block; one (4,) psum replicates the scalars (+zeros: exact)."""
         S, C_loc = self._S, self._C_loc
@@ -553,7 +559,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
                 row = jax.tree.map(
                     lambda l: jax.lax.dynamic_index_in_dim(
                         l, cl, 0, keepdims=False), state.cluster_params)
-                tau = self.task.hidden_mean(row, self._x256)
+                tau = self.task.hidden_mean(row, tables.x[:256])
             else:
                 tau = jnp.float32(0.0)
             return jnp.stack([loss, mean_freq, good, tau])
@@ -582,16 +588,18 @@ class ClusterMajorEngine(DeviceScaleEngine):
         m_specs = {"a": P(), "dur": P(), "consumed": P(), "loss": P()}
         sm = jax.shard_map(
             self._cm_round_local, mesh=self.placement.mesh,
-            in_specs=(pspecs, P(), P(), P(), P(), dev, dev, dev, dev),
+            in_specs=(pspecs, P(), P(), P(), P(), dev, dev, dev, dev, P()),
             out_specs=(pspecs, P(), P(), m_specs),
             check_vma=False)
         return jax.jit(sm)
 
-    def _cm_event_round(self, state, c, a_raw, members=None, mask=None):
+    def _cm_event_round(self, state, tables, c, a_raw, members=None,
+                        mask=None):
         """Event-path round: `_round_fn`-compatible host wrapper (the
         members/mask args of the parent's signature are unused — the
-        layout *is* the membership)."""
-        del members, mask
+        layout *is* the membership — and ``tables``, `self.tables`, rides
+        in `_statics`)."""
+        del tables, members, mask
         if self._event_fn is None:
             self._event_fn = self._build_event_fn()
         state, self._ftbl, self._ch3, m = self._event_fn(
@@ -603,16 +611,17 @@ class ClusterMajorEngine(DeviceScaleEngine):
         pspecs = self._cm_pspecs()
         dev = P(self._ax)
 
-        def fn(state, ftbl, ch3, c, oos_l, misb_l, mskslot_l, validc_l):
+        def fn(state, ftbl, ch3, c, oos_l, misb_l, mskslot_l, validc_l,
+               tables):
             del oos_l, misb_l, validc_l
             return self._cm_feats_local(state, ftbl, ch3, c, mskslot_l,
-                                        True)
+                                        True, tables)
 
         f_specs = {"cluster_loss": P(), "mean_freq": P(),
                    "channel_good_frac": P(), "cluster_freq": P()}
         sm = jax.shard_map(
             fn, mesh=self.placement.mesh,
-            in_specs=(pspecs, P(), P(), P(), dev, dev, dev, dev),
+            in_specs=(pspecs, P(), P(), P(), dev, dev, dev, dev, P()),
             out_specs=(f_specs, P()), check_vma=False)
         return jax.jit(sm)
 
@@ -624,8 +633,8 @@ class ClusterMajorEngine(DeviceScaleEngine):
         C_pad, C_loc, n = self._C_pad, self._C_loc, self._n
         ax = self._ax
 
-        def aux(state, oos_l, misb_l, mskslot_l, validc_l):
-            del oos_l, misb_l, validc_l
+        def aux(state, oos_l, misb_l, mskslot_l, validc_l, tables):
+            del oos_l, misb_l, validc_l, tables
             g = jax.lax.axis_index(ax)
             f_loc = self._local_freq_table(state.twins, mskslot_l)
             msk_f = mskslot_l.astype(jnp.float32)
@@ -638,7 +647,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
             return vec[:C_pad], vec[C_pad:] / n
 
         sm = jax.shard_map(aux, mesh=self.placement.mesh,
-                       in_specs=(pspecs, dev, dev, dev, dev),
+                       in_specs=(pspecs, dev, dev, dev, dev, P()),
                        out_specs=(P(), P()), check_vma=False)
         return jax.jit(sm)
 
@@ -651,14 +660,15 @@ class ClusterMajorEngine(DeviceScaleEngine):
         ctl_spec = jax.tree.map(lambda _: P(), pol.state)
 
         def local(state, times, ctl, energy, ftbl, ch3,
-                  oos_l, misb_l, mskslot_l, validc_l):
+                  oos_l, misb_l, mskslot_l, validc_l, tables):
             def body(carry, _):
                 state, times, ctl, energy, ftbl, ch3 = carry
                 c = jnp.argmin(times).astype(jnp.int32)
                 t = times[c]
                 with jax.named_scope("fl.control"):
                     feats, obs48 = self._cm_feats_local(
-                        state, ftbl, ch3, c, mskslot_l, pol.needs_obs)
+                        state, ftbl, ch3, c, mskslot_l, pol.needs_obs,
+                        tables)
                     cobs = ctl_policy.CtlObs(
                         round=state.round, cluster=c, queue=state.queue,
                         cluster_loss=feats["cluster_loss"],
@@ -669,7 +679,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
                     a_raw, ctl = pol.step(ctl, cobs)
                 state, ftbl, ch3, m = self._cm_round_local(
                     state, ftbl, ch3, c, a_raw,
-                    oos_l, misb_l, mskslot_l, validc_l)
+                    oos_l, misb_l, mskslot_l, validc_l, tables)
                 times = times.at[c].set(t + m["dur"])
                 energy = energy + m["consumed"]
                 ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
@@ -684,7 +694,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
         sm = jax.shard_map(
             local, mesh=self.placement.mesh,
             in_specs=(pspecs, P(), ctl_spec, P(), P(), P(),
-                      dev, dev, dev, dev),
+                      dev, dev, dev, dev, P()),
             out_specs=((pspecs, P(), ctl_spec, P(), P(), P()), ys_specs),
             check_vma=False)
         return jax.jit(sm)

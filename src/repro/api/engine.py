@@ -25,7 +25,10 @@ update, Eqn-6 aggregation through the masked Pallas ``trust_aggregate``
 kernel, the optional DP path, energy accounting (Eqns 7-8), the twin
 observe/calibrate step, and the Eqn-19 staleness-weighted global average —
 is **one fused jit-compiled call** `_fleet_round(state, c, a, members,
-mask)`.  Only the event heap, the controller's `select`, evaluation, and
+mask)`.  The deployment's read-only tables (data, padded partition,
+membership, attacker masks) are one `FleetTables` pytree that every round
+program takes as an argument, not as captured constants (`_reading`).
+Only the event heap, the controller's `select`, evaluation, and
 the float64 cumulative-energy tally stay on the host: a single 4-scalar
 metrics dict (bounded a, round duration, consumed energy, mean loss)
 crosses the device boundary per round.
@@ -82,6 +85,7 @@ the same controller protocol and emits the same `RoundRecord` trace.
 """
 from __future__ import annotations
 
+import copy
 import heapq
 from typing import (Any, NamedTuple, Optional, Protocol, runtime_checkable)
 
@@ -181,6 +185,23 @@ class FleetState(NamedTuple):
                                 # round-trips it via its __key__: marker
 
 
+class FleetTables(NamedTuple):
+    """The deployment's read-only tables, built once at engine init and
+    held on the device.  Every round program takes them as a (non-donated)
+    argument, never as baked-in constants, so the data live on the device
+    once and two fleets of the same shapes share one compiled program.
+    They are not state: `FleetState`, `resumable_state` and checkpoints
+    leave them out (a resumed engine rebuilds them from the spec)."""
+    x: jnp.ndarray              # (n_samples, dim) features
+    y: jnp.ndarray              # (n_samples,) labels
+    part_idx: jnp.ndarray       # (n, W) int32 padded shard rows
+    part_len: jnp.ndarray       # (n,) int32 shard sizes
+    member_table: jnp.ndarray   # (n_clusters, M) int32, sentinel n pads
+    member_mask: jnp.ndarray    # (n_clusters, M) bool
+    malicious: jnp.ndarray      # (n,) f32 label-flip attackers
+    misbehaving: jnp.ndarray    # (n,) f32 attackers + Byzantine subsets
+
+
 class DeviceScaleEngine:
     """Discrete-event asynchronous clustered FL over a device fleet."""
 
@@ -218,15 +239,14 @@ class DeviceScaleEngine:
             # stream in the engine untouched
             assign, _ = cluster_devices(kc, twins, C)
         self.assign = ensure_nonempty(np.asarray(assign), C)
-        self._member_table, self._member_mask = padded_membership(
-            self.assign, C)
+        member_table, member_mask = padded_membership(self.assign, C)
 
         self.malicious = np.zeros(n, bool)
         n_mal = int(spec.fleet.malicious_frac * n)
         if n_mal:
             self.malicious[np.asarray(jax.random.choice(
                 km, n, (n_mal,), replace=False))] = True
-        self._malicious_dev = jnp.asarray(self.malicious, jnp.float32)
+        malicious = jnp.asarray(self.malicious, jnp.float32)
 
         # declarative fault injection (spec.faults -> pure-jnp round
         # transforms); the default spec is inert and the gating below is
@@ -238,9 +258,15 @@ class DeviceScaleEngine:
         # round a misbehaving member's beta count grows, so reputation —
         # not just the per-round FoolsGold signals — learns persistent
         # attackers (inert spec: both subsets are zero, nothing changes)
-        self._misbehaving_dev = jnp.maximum(
-            self._malicious_dev,
+        misbehaving = jnp.maximum(
+            malicious,
             jnp.maximum(self.faults.corrupt_dev, self.faults.poison_dev))
+        part_idx, part_len = padded_partition(parts)
+        self.tables = self.placement.replicate(FleetTables(
+            x=jnp.asarray(data.x), y=jnp.asarray(data.y),
+            part_idx=part_idx, part_len=part_len,
+            member_table=member_table, member_mask=member_mask,
+            malicious=malicious, misbehaving=misbehaving))
 
         gp = task.init(kp, dim=data.x.shape[1])
         cparams = jax.tree.map(
@@ -256,10 +282,6 @@ class DeviceScaleEngine:
         # (+inf for budgetless controllers: the queue leaf stays 0)
         self._queue_per_slot = ctl_queue.per_slot_of(controller)
 
-        # static fleet tables consumed by the fused round
-        self._x = jnp.asarray(data.x)
-        self._y = jnp.asarray(data.y)
-        self._part_idx, self._part_len = padded_partition(parts)
         self._trans = channel_transition(spec.channel.p_good)
         self._n_actions = int(getattr(controller, "n_actions", 10))
         self._needs_ctx = bool(getattr(controller, "needs_ctx", True))
@@ -267,8 +289,8 @@ class DeviceScaleEngine:
         # rank-statistic rules get exact member shapes (one compile per size)
         self._padded = bool(getattr(aggregator, "supports_mask", False))
         if self._padded:
-            self._members = [self._member_table[c] for c in range(C)]
-            self._masks = [self._member_mask[c] for c in range(C)]
+            self._members = [member_table[c] for c in range(C)]
+            self._masks = [member_mask[c] for c in range(C)]
         else:
             self._members = [jnp.asarray(np.where(self.assign == c)[0],
                                          jnp.int32) for c in range(C)]
@@ -291,9 +313,8 @@ class DeviceScaleEngine:
             jit_kw["out_shardings"] = (
                 self.placement.state_shardings(self.state),
                 {"a": repl, "dur": repl, "consumed": repl, "loss": repl})
-        self._round_fn = (
-            jax.jit(self._fleet_round, **jit_kw)
-            if self.fused else self._fleet_round)
+        self._round_fn = (jax.jit(self._round, **jit_kw) if self.fused
+                          else self._round)
         self._rounds = 0
         # cumulative energy accumulates host-side in float64 (the per-round
         # `consumed` scalar crosses to the host anyway); a float32 device
@@ -326,9 +347,10 @@ class DeviceScaleEngine:
         compiles.install()              # counts every compile, obs or not
         # control plane: jitted host ctx features / observation builders
         # + compiled scan paths
-        self._features_fn = jax.jit(self._ctl_features)
-        self._obs_fn = jax.jit(lambda state, c: self._scan_obs(
-            state, c, self._ctl_features(state, c)))
+        self._features_fn = jax.jit(
+            lambda state, tables, c: self._reading(tables)._ctl_features(
+                state, c))
+        self._obs_fn = jax.jit(self._obs_of)
         self._scan_cache = {}       # K -> compiled lax.scan-over-rounds
 
     # ------------------------------------------------------------------ #
@@ -392,19 +414,24 @@ class DeviceScaleEngine:
 
         With no obs attached, returns ``fn`` unchanged (the plain jit
         path — compilation happens implicitly on first call, exactly as
-        before).  Under telemetry, lower+compile explicitly (AOT builds
-        the *same* executable the first jit call would) inside a
+        before).  Under telemetry, trace+lower+compile explicitly (AOT
+        builds the *same* executable the first jit call would) inside a
         ``span("compile")``, and feed the optimized HLO through
-        `hlo_stats.analyze_module` for the one-time compile event."""
+        `hlo_stats.analyze_module` and the bytes of the constants the
+        trace captured (``fl_program_const_bytes``) into the one-time
+        compile event."""
         if self.obs is None:
             return fn
         with self.obs.span("compile", fn=name) as sp:
-            compiled = fn.lower(*args).compile()
+            traced = fn.trace(*args)
+            compiled = traced.lower().compile()
         try:
             hlo = compiled.as_text()
         except Exception:
             hlo = None
-        self.obs.record_compile(name, sp.dur_s, hlo)
+        self.obs.record_compile(name, sp.dur_s, hlo,
+                                const_bytes=compiles.const_bytes(
+                                    traced.jaxpr))
         return compiled
 
     def _compile_scan(self, K: int, pol, args, name: str):
@@ -479,16 +506,35 @@ class DeviceScaleEngine:
     # ------------------------------------------------------------------ #
     # the fused round: everything below runs inside one jit call
     # ------------------------------------------------------------------ #
+    def _reading(self, tables: FleetTables) -> "DeviceScaleEngine":
+        """This engine reading ``tables`` in place of its own.  Each round
+        program traces the round methods on such a view of its ``tables``
+        argument, so the fleet's data enter the program as an argument and
+        never as a constant."""
+        view = copy.copy(self)
+        view.tables = tables
+        return view
+
+    def _round(self, state: FleetState, tables: FleetTables, c, a_raw,
+               members, mask):
+        """`_fleet_round` over the ``tables`` argument (the per-event jit)."""
+        return self._reading(tables)._fleet_round(state, c, a_raw, members,
+                                                  mask)
+
+    def _obs_of(self, state: FleetState, tables: FleetTables, c):
+        view = self._reading(tables)
+        return view._scan_obs(state, c, view._ctl_features(state, c))
+
     def _cluster_freq_table(self, twins) -> jnp.ndarray:
         """Straggler (min) calibrated frequency of every cluster, (C,).
         One masked reduction over the padded membership table per call —
         the old engine recomputed the full-fleet `calibrated_freq` O(C^2)
         times per frequency pick."""
+        tb = self.tables
         f = calibrated_freq(twins)
-        fmat = f.at[self._member_table].get(mode="fill",
-                                            fill_value=jnp.inf)
-        fmin = jnp.min(jnp.where(self._member_mask, fmat, jnp.inf), axis=1)
-        return jnp.where(self._member_mask.any(axis=1), fmin, 1.0)
+        fmat = f.at[tb.member_table].get(mode="fill", fill_value=jnp.inf)
+        fmin = jnp.min(jnp.where(tb.member_mask, fmat, jnp.inf), axis=1)
+        return jnp.where(tb.member_mask.any(axis=1), fmin, 1.0)
 
     def _fleet_round(self, state: FleetState, c, a_raw, members, mask):
         """One asynchronous cluster round (paper §IV-D), state -> state.
@@ -542,17 +588,18 @@ class DeviceScaleEngine:
             a = jnp.clip(a, 1, self._n_actions)
 
             # --- local batches from the padded partition matrix
-            sel = sample_member_batch(kb, self._part_idx, self._part_len,
-                                      members, spec.local_batch)
-            x = self._x[sel]
-            y = self._y[sel]
+            tb = self.tables
+            with jax.named_scope("fl.gather"):
+                sel = sample_member_batch(kb, tb.part_idx, tb.part_len,
+                                          members, spec.local_batch)
+                x = tb.x[sel]
+                y = tb.y[sel]
             if fm.may_poison:
                 # poisons the sampled features before they enter local_train;
                 # for reconstruction tasks (corrupt_labels a no-op) this is the
                 # only attack surface that touches the loss
                 x = fm.poison_inputs(kflt, x, members)
-            mal_m = self._malicious_dev.at[members].get(mode="fill",
-                                                        fill_value=0.0)
+            mal_m = tb.malicious.at[members].get(mode="fill", fill_value=0.0)
             y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
             batch = {"x": x, "y": y}
 
@@ -614,7 +661,7 @@ class DeviceScaleEngine:
                              members=members) * mask_f
             consumed = jnp.sum(e)
             twins = observe_round_members(twins, members, losses, e,
-                                          self._misbehaving_dev)
+                                          tb.misbehaving)
             if spec.fleet.calibrate_dt:
                 twins = calibrate(twins)
             channel = step_channel(kc2, state.channel, self._trans)
@@ -686,8 +733,8 @@ class DeviceScaleEngine:
         host and in-jit ``select`` see identical device math.
         """
         twins = state.twins
-        members = self._member_table[c]
-        mask = self._member_mask[c]
+        members = self.tables.member_table[c]
+        mask = self.tables.member_mask[c]
         mask_f = mask.astype(jnp.float32)
         cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
 
@@ -717,7 +764,7 @@ class DeviceScaleEngine:
         """
         tau = self.task.hidden_mean(
             jax.tree.map(lambda l: l[c], state.cluster_params),
-            self._x[:256])
+            self.tables.x[:256])
         return ctl_policy.deploy_obs(
             feats["cluster_loss"], state.queue,
             state.round.astype(jnp.float32) / 100.0, tau,
@@ -730,10 +777,11 @@ class DeviceScaleEngine:
     def _obs(self, c: int) -> jnp.ndarray:
         """DQN observation for host-side `select`: the same `_scan_obs`
         function the scanned path traces, as one jitted call."""
-        return self._obs_fn(self.state, jnp.int32(c))
+        return self._obs_fn(self.state, self.tables, jnp.int32(c))
 
     def _ctx(self, c: int) -> ControllerCtx:
-        f = jax.device_get(self._features_fn(self.state, jnp.int32(c)))
+        f = jax.device_get(self._features_fn(self.state, self.tables,
+                                             jnp.int32(c)))
         return ControllerCtx(
             round=self._rounds, cluster=c, obs=lambda: self._obs(c),
             cluster_loss=float(f["cluster_loss"]),
@@ -754,15 +802,15 @@ class DeviceScaleEngine:
     # scan-over-rounds: K rounds + in-jit controller in one lax.scan
     # ------------------------------------------------------------------ #
     def _build_scan_fn(self, K: int, pol: ctl_policy.ScanPolicy):
-        def body(carry, _):
+        def body(eng, carry):
             state, times, ctl, energy = carry
             # the event heap pops min (t, c); argmin breaks ties on the
             # first (lowest) cluster index exactly as tuple order does
             c = jnp.argmin(times).astype(jnp.int32)
             t = times[c]
             with jax.named_scope("fl.control"):
-                feats = self._ctl_features(state, c)
-                obs48 = (self._scan_obs(state, c, feats)
+                feats = eng._ctl_features(state, c)
+                obs48 = (eng._scan_obs(state, c, feats)
                          if pol.needs_obs else jnp.zeros((OBS_DIM,),
                                                          jnp.float32))
                 cobs = ctl_policy.CtlObs(
@@ -773,32 +821,36 @@ class DeviceScaleEngine:
                     channel_good_frac=feats["channel_good_frac"],
                     energy_used=energy, dqn_obs=obs48)
                 a_raw, ctl = pol.step(ctl, cobs)
-            state, m = self._fleet_round(
-                state, c, a_raw, self._member_table[c],
-                self._member_mask[c])
+            state, m = eng._fleet_round(
+                state, c, a_raw, eng.tables.member_table[c],
+                eng.tables.member_mask[c])
             times = times.at[c].set(t + m["dur"])
             energy = energy + m["consumed"]
             ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
                   "consumed": m["consumed"], "loss": m["loss"]}
             return (state, times, ctl, energy), ys
 
-        def run_k(state, times, ctl, energy):
-            return jax.lax.scan(body, (state, times, ctl, energy), None,
-                                length=K)
+        def run_k(state, times, ctl, energy, tables):
+            eng = self._reading(tables)
+            return jax.lax.scan(lambda carry, _: body(eng, carry),
+                                (state, times, ctl, energy), None, length=K)
 
         jit_kw = dict(donate_argnums=(0,))
         if self.placement.is_sharded:
             # carry: FleetState by leaf group, the per-cluster event-time
             # vector with the cluster stack, policy carry + energy tally
-            # replicated; the K stacked metrics replicate (synced once)
+            # replicated; the K stacked metrics replicate (synced once);
+            # the fleet tables replicate
             repl = self.placement.replicated()
             carry_sh = (self.placement.state_shardings(self.state),
                         self.placement.sharding(self.placement.cluster_axis),
                         self.placement.tree_replicated(pol.state), repl)
             ys_sh = {k: repl for k in ("t", "cluster", "a", "dur",
                                        "consumed", "loss")}
-            jit_kw.update(in_shardings=carry_sh,
-                          out_shardings=(carry_sh, ys_sh))
+            jit_kw.update(
+                in_shardings=carry_sh + (
+                    self.placement.tree_replicated(self.tables),),
+                out_shardings=(carry_sh, ys_sh))
         return jax.jit(run_k, **jit_kw)
 
     def run_scanned(self, K: int, *, eval_final: bool = True) -> FLTrace:
@@ -837,7 +889,7 @@ class DeviceScaleEngine:
         with self._obs_span("prepare"):
             pol = scan_policy()
             args = (self.state, self._scan_times, pol.state,
-                    self._scan_energy_start())
+                    self._scan_energy_start(), self.tables)
             fn = self._scan_cache.get(K)
         if fn is None:
             fn = self._compile_scan(K, pol, args, "run_scanned")
@@ -954,7 +1006,8 @@ class DeviceScaleEngine:
             ctx = self._ctx(c) if self._needs_ctx else self._null_ctx(c)
             a_raw = int(self.controller.select(ctx))
             self.state, metrics = self._round_fn(
-                self.state, c, a_raw, self._members[c], self._masks[c])
+                self.state, self.tables, c, a_raw, self._members[c],
+                self._masks[c])
             self._rounds += 1
             done += 1
             m = jax.device_get(metrics)

@@ -100,6 +100,12 @@ class Placement:
             return state
         return jax.device_put(state, self.state_shardings(state))
 
+    def replicate(self, tree) -> Any:
+        """Commit every leaf of ``tree`` replicated over the mesh."""
+        if not self.is_sharded:
+            return tree
+        return jax.device_put(tree, self.tree_replicated(tree))
+
 
 SINGLE_DEVICE = Placement()
 

@@ -80,6 +80,14 @@ def install() -> bool:
     return True
 
 
+def const_bytes(closed_jaxpr) -> int:
+    """Bytes of the array constants a traced program captured (the
+    ``consts`` of its `ClosedJaxpr`, e.g. ``jax.jit(f).trace(...).jaxpr``):
+    data the compiled program carries in its own text instead of taking
+    as an argument."""
+    return int(sum(getattr(c, "nbytes", 0) for c in closed_jaxpr.consts))
+
+
 def counts() -> Dict[str, float]:
     """The process's totals since `install()`: ``lowerings``, backend
     ``compiles`` and their seconds ``compile_s``."""

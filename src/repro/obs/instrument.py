@@ -91,6 +91,9 @@ class EngineObs:
             "fl_hlo_collective_ops", "collective op count in optimized HLO")
         self.m_hlo_flops = r.gauge(
             "fl_hlo_flops", "estimated FLOPs of the compiled program")
+        self.m_const_bytes = r.gauge(
+            "fl_program_const_bytes",
+            "bytes of array constants the compiled program captured")
         self.m_ckpts = r.counter("fl_checkpoints_total", "checkpoints taken")
         self.m_ckpt_s = r.histogram(
             "fl_checkpoint_seconds", "checkpoint wall-clock latency")
@@ -190,13 +193,19 @@ class EngineObs:
             self.m_ckpt_bytes.set(float(nbytes))
 
     def record_compile(self, fn_name: str, seconds: float,
-                       hlo_text: Optional[str] = None) -> None:
-        """One-time compile event: HLO collective stats + an ``event/1``
-        record in metrics.jsonl (the compile counters are fed by
-        `repro.obs.compiles`, which sees this compile too)."""
+                       hlo_text: Optional[str] = None,
+                       const_bytes: Optional[int] = None) -> None:
+        """One-time compile event: HLO collective stats, the bytes of
+        array constants the program captured (``const_bytes``; data it
+        should take as arguments) + an ``event/1`` record in metrics.jsonl
+        (the compile counters are fed by `repro.obs.compiles`, which sees
+        this compile too)."""
         event = {"schema": EVENT_SCHEMA, "event": "compile",
                  "ts": time.time(), "fn": fn_name,
                  "seconds": float(seconds)}
+        if const_bytes is not None:
+            self.m_const_bytes.set(float(const_bytes), fn=fn_name)
+            event["const_bytes"] = int(const_bytes)
         if hlo_text is not None:
             from repro.launch.hlo_stats import analyze_module
             try:

@@ -170,31 +170,21 @@ class PopulationEngine:
         self.state = jax.tree.map(lambda *ls: jnp.stack(ls),
                                   *[e.state for e in engines])
         self._scan_times = stack(e._scan_times for e in engines)
-        M = max(e._member_table.shape[1] for e in engines)
-        W = max(e._part_idx.shape[1] for e in engines)
+        M = max(e.tables.member_table.shape[1] for e in engines)
+        W = max(e.tables.part_idx.shape[1] for e in engines)
         n = self.n_devices
 
-        def pad_tbl(e):
-            pad = M - e._member_table.shape[1]
-            tbl = jnp.pad(e._member_table, ((0, 0), (0, pad)),
-                          constant_values=n)
-            msk = jnp.pad(e._member_mask, ((0, 0), (0, pad)),
-                          constant_values=False)
-            return tbl, msk
+        def padded(t):
+            pad = lambda a, w, fill: jnp.pad(                  # noqa: E731
+                a, ((0, 0), (0, w - a.shape[1])), constant_values=fill)
+            return t._replace(part_idx=pad(t.part_idx, W, 0),
+                              member_table=pad(t.member_table, M, n),
+                              member_mask=pad(t.member_mask, M, False))
 
-        tbls, msks = zip(*(pad_tbl(e) for e in engines))
+        # the members' fleet tables, stacked: the scan's argument
+        self._tables = jax.tree.map(lambda *ls: jnp.stack(ls),
+                                    *[padded(e.tables) for e in engines])
         mp: Dict[str, Any] = {
-            "x": stack(e._x for e in engines),
-            "y": stack(e._y for e in engines),
-            "part_idx": stack(
-                jnp.pad(e._part_idx,
-                        ((0, 0), (0, W - e._part_idx.shape[1])))
-                for e in engines),
-            "part_len": stack(e._part_len for e in engines),
-            "member_table": stack(tbls),
-            "member_mask": stack(msks),
-            "malicious": stack(e._malicious_dev for e in engines),
-            "misbehaving": stack(e._misbehaving_dev for e in engines),
             "trans": stack(e._trans for e in engines),
             "per_slot": jnp.asarray(
                 [ctl_queue.per_slot_of(f.controller)
@@ -246,6 +236,7 @@ class PopulationEngine:
             self.state = put(self.state)
             self._scan_times = jax.device_put(self._scan_times, sh)
             self._mp = put(self._mp)
+            self._tables = put(self._tables)
 
         self._rounds = [0] * self.B
         self._energy_used = [0.0] * self.B      # exact f64, per member
@@ -379,9 +370,10 @@ class PopulationEngine:
         return ctl
 
     # ------------------------------------------------------------------ #
-    def _member_view(self, mp: Dict[str, Any]) -> _MemberView:
-        """Bind one member's vmap-sliced leaves + lifted scalars to a
-        duck-typed engine the inherited round methods run against."""
+    def _member_view(self, mp: Dict[str, Any], tables) -> _MemberView:
+        """Bind one member's vmap-sliced leaves (its fleet ``tables``) +
+        lifted scalars to a duck-typed engine the inherited round methods
+        run against."""
         e0 = self._proto
         s0 = e0.spec
         spec = dataclasses.replace(
@@ -401,21 +393,15 @@ class PopulationEngine:
         return _MemberView(
             spec=spec, task=e0.task, faults=faults, aggregator=aggregator,
             _sentinel=e0._sentinel, _n_actions=e0._n_actions,
-            _padded=True, _fused_global=e0._fused_global,
-            _member_table=mp["member_table"],
-            _member_mask=mp["member_mask"],
-            _part_idx=mp["part_idx"], _part_len=mp["part_len"],
-            _x=mp["x"], _y=mp["y"],
-            _malicious_dev=mp["malicious"],
-            _misbehaving_dev=mp["misbehaving"],
+            _padded=True, _fused_global=e0._fused_global, tables=tables,
             _trans=mp["trans"], _queue_per_slot=mp["per_slot"])
 
     def _build_scan_fn(self, K: int):
         pol_step = self._pol_step
         needs_obs = self._pol_needs_obs
 
-        def member_body(state, times, ctl, energy, mp):
-            view = self._member_view(mp)
+        def member_body(state, times, ctl, energy, mp, tables):
+            view = self._member_view(mp, tables)
             c = jnp.argmin(times).astype(jnp.int32)
             t = times[c]
             feats = view._ctl_features(state, c)
@@ -430,22 +416,21 @@ class PopulationEngine:
                 energy_used=energy, dqn_obs=obs48)
             a_raw, ctl = pol_step(ctl, cobs, mp.get("pol"))
             state, m = view._fleet_round(
-                state, c, a_raw, view._member_table[c],
-                view._member_mask[c])
+                state, c, a_raw, tables.member_table[c],
+                tables.member_mask[c])
             times = times.at[c].set(t + m["dur"])
             energy = energy + m["consumed"]
             ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
                   "consumed": m["consumed"], "loss": m["loss"]}
             return (state, times, ctl, energy), ys
 
-        vbody = jax.vmap(member_body, in_axes=(0, 0, 0, 0, 0))
+        vbody = jax.vmap(member_body, in_axes=(0, 0, 0, 0, 0, 0))
         mp = self._mp
 
-        def body(carry, _):
-            state, times, ctl, energy = carry
-            return vbody(state, times, ctl, energy, mp)
-
-        def run_k(state, times, ctl, energy):
+        def run_k(state, times, ctl, energy, tables):
+            def body(carry, _):
+                state, times, ctl, energy = carry
+                return vbody(state, times, ctl, energy, mp, tables)
             return jax.lax.scan(body, (state, times, ctl, energy), None,
                                 length=K)
 
@@ -459,8 +444,9 @@ class PopulationEngine:
                                       PartitionSpec(None, self.pop_axis))
                      for k in ("t", "cluster", "a", "dur", "consumed",
                                "loss")}
-            jit_kw.update(in_shardings=carry_sh,
-                          out_shardings=(carry_sh, ys_sh))
+            jit_kw.update(in_shardings=carry_sh + (
+                jax.tree.map(lambda _: pop, self._tables),),
+                out_shardings=(carry_sh, ys_sh))
         return jax.jit(run_k, **jit_kw)
 
     # ------------------------------------------------------------------ #
@@ -482,7 +468,8 @@ class PopulationEngine:
         if self.mesh is not None:
             energy0 = jax.device_put(energy0, NamedSharding(
                 self.mesh, PartitionSpec(self.pop_axis)))
-        args = (self.state, self._scan_times, self._ctl_state(), energy0)
+        args = (self.state, self._scan_times, self._ctl_state(), energy0,
+                self._tables)
         fn = self._scan_cache.get(K)
         if fn is None:
             fn = self._build_scan_fn(K)
