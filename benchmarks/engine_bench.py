@@ -69,7 +69,6 @@ import numpy as np
 from repro.api import (AggregatorSpec, ClusteringSpec, ControllerSpec,
                        Federation, FederationSpec, FleetSpec, ShardingSpec,
                        WeightedAggregator)
-from repro.api.engine import _flatten_params
 from repro.core.clustering import (cluster_devices, ensure_nonempty,
                                    tolerance_bound)
 from repro.core.energy import (channel_transition, comm_energy,
@@ -80,6 +79,7 @@ from repro.core.trust import (belief, gradient_diversity, learning_quality,
 from repro.core.twin import (TwinState, calibrate, calibrated_freq,
                              init_twins, observe_round, sample_deviation)
 from repro.data import dirichlet_partition, make_classification
+from repro.kernels.ops import flatten_rows
 
 
 class LegacyEngine:
@@ -151,7 +151,7 @@ class LegacyEngine:
             lambda x: jnp.broadcast_to(x, (len(members),) + x.shape),
             self.cluster_params[c])
         new = self.task.local_train(stacked, batch, spec.lr, a)
-        upd_flat = _flatten_params(new) - _flatten_params(stacked)
+        upd_flat = flatten_rows(new) - flatten_rows(stacked)
         q = learning_quality(upd_flat)
         div = gradient_diversity(upd_flat)
         tw_m = jax.tree.map(lambda x: x[members], self.twins._asdict())

@@ -78,7 +78,7 @@ from repro.core.trust import (belief, gradient_diversity, learning_quality,
 from repro.core.twin import TwinState, calibrate, calibrated_freq
 from repro.data.federated import sample_member_batch
 
-from .engine import DeviceScaleEngine, FleetState, _flatten_params
+from .engine import DeviceScaleEngine, FleetState, _flat_members
 from .placement import shard_map_placement
 from .spec import ShardingSpec
 
@@ -374,7 +374,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
             if fm.may_corrupt:
                 new = fm.corrupt_updates(kflt, new, stacked, members)
 
-            upd_flat = _flatten_params(new) - _flatten_params(stacked)
+            _, upd_flat = _flat_members(new, cur_row)
             q = learning_quality(upd_flat, mask)
             div = gradient_diversity(upd_flat, mask)
             tw_m = TwinState(*[

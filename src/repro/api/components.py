@@ -54,8 +54,7 @@ from repro.core.robust import AGGREGATORS as ROBUST_RULES
 from repro.core.robust import MASKED_AGGREGATORS as MASKED_RULES
 from repro.core.trust import trust_weighted_average
 from repro.core.twin import calibrated_freq
-from repro.kernels.ops import (trust_aggregate_global_tree,
-                               trust_aggregate_tree)
+from repro.kernels.ops import trust_aggregate_global_flat, trust_aggregate_tree
 
 from .registry import (register_aggregator, register_controller,
                        register_task)
@@ -91,7 +90,7 @@ class WeightedAggregator:
         self.uniform = uniform
         self.use_kernel = use_kernel
         # the kernel path can fold the Eqn-19 global average into the same
-        # grid pass (`aggregate_with_global`); the engine consults this
+        # grid pass (`aggregate_flat_with_global`); the engine consults this
         self.supports_fused_global = use_kernel
 
     def _effective_weights(self, weights, mask):
@@ -110,15 +109,17 @@ class WeightedAggregator:
             weights = weights * mask.astype(weights.dtype)
         return trust_weighted_average(client_params, weights)
 
-    def aggregate_with_global(self, client_params, weights, mask,
-                              cluster_stack, staleness_w, c):
+    def aggregate_flat_with_global(self, updates_flat, weights, mask,
+                                   cluster_stack, staleness_w, c):
         """Fused Eqn 6 + Eqn 19: member updates -> the post-round global
         model in one `trust_aggregate_global` kernel pass (the Eqn-6
         aggregate replaces row ``c`` of the stacked cluster parameters
-        in-VMEM before the staleness-weighted average)."""
+        in-VMEM before the staleness-weighted average).  The updates come
+        as one (C, W) matrix (`kernels.ops.flatten_rows`), which the engine
+        builds once at the kernel's padded width."""
         weights = self._effective_weights(weights, mask)
-        return trust_aggregate_global_tree(
-            client_params, weights, mask, cluster_stack, staleness_w, c)
+        return trust_aggregate_global_flat(
+            updates_flat, weights, mask, cluster_stack, staleness_w, c)
 
 
 class RobustAggregator:

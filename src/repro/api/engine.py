@@ -107,6 +107,8 @@ from repro.core.twin import (calibrate, calibrated_freq, init_twins,
                              sample_deviation, TwinState)
 from repro.data.federated import padded_partition, sample_member_batch
 from repro.faults import FaultModel
+from repro.kernels.ops import flatten_rows
+from repro.kernels.trust_aggregate import padded_width
 from repro.obs import annotate, compiles
 
 from . import placement as placement_lib
@@ -122,9 +124,23 @@ from .spec import (DATACENTER_SCALE, DEVICE_SCALE, FederationSpec,
 GSPMD_DEVICE_SCALE = "device-gspmd"
 
 
-def _flatten_params(tree):
-    return jnp.concatenate([x.reshape(x.shape[0], -1)
-                            for x in jax.tree.leaves(tree)], axis=1)
+def _flat_members(new, row, width=None):
+    """The round's member matrix, built once.
+
+    Returns ``flat_new = flatten_rows(new, width)`` and the members'
+    (M, N) updates: ``flat_new`` less ``row``, the cluster model local SGD
+    started from (no member dim), flattened to the same width, cut to the
+    N parameter columns.  One row is subtracted from every member, so no
+    (M, N) copy of the cluster model is made, and the Eqns 4-5 statistics
+    reduce over the parameters alone.  ``width`` defaults to N; the fused
+    round passes the trust kernel's `padded_width`, at which the kernel
+    reads ``flat_new`` (zeros past N) with no pad of its own.  The cut
+    comes after the subtraction: cut first, XLA builds a second, unpadded
+    copy of the matrix for it."""
+    flat_new = flatten_rows(new, width)
+    flat_row = flatten_rows(jax.tree.map(lambda l: l[None], row), width)
+    n = sum(l.size for l in jax.tree.leaves(row))
+    return flat_new, (flat_new - flat_row)[:, :n]
 
 
 @runtime_checkable
@@ -297,7 +313,7 @@ class DeviceScaleEngine:
             self._masks = [jnp.ones((len(g),), bool) for g in self._members]
 
         # aggregators exposing the fused Eqn-6+19 kernel path
-        # (`aggregate_with_global`) fold the global average into the same
+        # (`aggregate_flat_with_global`) fold the global average into the same
         # pass when the round is padded and DP is off
         self._fused_global = self._padded and bool(
             getattr(aggregator, "supports_fused_global", False))
@@ -606,9 +622,9 @@ class DeviceScaleEngine:
         with jax.named_scope("fl.local_sgd"):
             # --- a local steps on every member (vmap), from the cluster model
             m_dim = members.shape[0]
+            cur = jax.tree.map(lambda l: l[c], state.cluster_params)
             stacked = jax.tree.map(
-                lambda l: jnp.broadcast_to(l[c], (m_dim,) + l.shape[1:]),
-                state.cluster_params)
+                lambda l: jnp.broadcast_to(l, (m_dim,) + l.shape), cur)
             new = task.local_train(stacked, batch, spec.lr, a)
             if fm.may_corrupt:
                 # Byzantine members replace their honest deltas *before* the
@@ -616,8 +632,18 @@ class DeviceScaleEngine:
                 new = fm.corrupt_updates(kflt, new, stacked, members)
 
         # --- trust update (Eqns 4-5) & pluggable aggregation (Eqn 6)
+        # with a fused-global aggregator the Eqn-6 aggregate never leaves
+        # the kernel (see the Eqn-19 block below); DP needs the bare
+        # aggregate to clip against, so it keeps the two-step path
+        fuse_global = self._fused_global and spec.privacy.clip <= 0.0
         with jax.named_scope("fl.flatten"):
-            upd_flat = _flatten_params(new) - _flatten_params(stacked)
+            # the kernel's width (member rows + the cluster stack's rows):
+            # it then streams flat_new with no pad of its own
+            stack = jax.tree.leaves(state.cluster_params)
+            width = (padded_width(m_dim + stack[0].shape[0],
+                                  sum(l[0].size for l in stack))
+                     if fuse_global else None)
+            flat_new, upd_flat = _flat_members(new, cur, width)
         with jax.named_scope("fl.trust"):
             q = learning_quality(upd_flat, mask)
             div = gradient_diversity(upd_flat, mask)
@@ -633,16 +659,11 @@ class DeviceScaleEngine:
             rep = state.rep.at[members].set(rep_m, mode="drop")
             w = trust_weights(rep_m, mask)
         with jax.named_scope("fl.aggregate"):
-            # with a fused-global aggregator the Eqn-6 aggregate never leaves
-            # the kernel (see the Eqn-19 block below); DP needs the bare
-            # aggregate to clip against, so it keeps the two-step path
-            fuse_global = self._fused_global and spec.privacy.clip <= 0.0
             if not fuse_global:
                 agg = (self.aggregator(new, w, mask) if self._padded
                        else self.aggregator(new, w))
                 if spec.privacy.clip > 0.0:
                     from repro.core.privacy import dp_aggregate
-                    cur = jax.tree.map(lambda l: l[c], state.cluster_params)
                     agg = dp_aggregate(
                         kdp, new, cur,
                         w if spec.aggregator.kind == "trust" else mask_f / cnt,
@@ -675,8 +696,8 @@ class DeviceScaleEngine:
                 # substitution into the cluster stack + the Eqn-19 average
                 # ((n_clusters + C, block) tiles per grid step; the per-shard
                 # unit under a mesh placement)
-                gparams = self.aggregator.aggregate_with_global(
-                    new, w, mask, state.cluster_params,
+                gparams = self.aggregator.aggregate_flat_with_global(
+                    flat_new, w, mask, state.cluster_params,
                     staleness_weights(rnd.astype(jnp.float32) - ts), c)
                 cparams = state.cluster_params
             else:

@@ -20,19 +20,53 @@ def _interpret(flag):
     return jax.default_backend() == "cpu" if flag is None else flag
 
 
-def _flatten_rows(tree):
-    leaves, treedef = jax.tree.flatten(tree)
-    C = leaves[0].shape[0]
-    flat = jnp.concatenate(
-        [x.reshape(C, -1).astype(jnp.float32) for x in leaves], axis=1)
-    return flat, leaves, treedef
+def _leaf_order(leaves):
+    """The flat matrix's column order: largest leaves first (stable), so
+    that the big weight blocks start on lane-aligned columns and the
+    small bias blocks take the unaligned offsets."""
+    return sorted(range(len(leaves)), key=lambda i: -leaves[i][0].size)
 
 
-def _unflatten_row(vec, leaves, treedef):
-    out, off = [], 0
-    for x in leaves:
+def _to_rows(x):
+    """(C, ...) -> (C, n), each row's weight matrix with its last two axes
+    swapped.  The chip keeps a stack of weight matrices with the
+    second-to-last axis minor (the batched local-SGD matmuls write it so),
+    so the swap is a bitcast and flattening is one relayout, not a
+    transpose and then a relayout."""
+    return (x.swapaxes(-1, -2) if x.ndim >= 3 else x).reshape(x.shape[0], -1)
+
+
+def _from_row(v, like):
+    """`_to_rows` undone for one row ``v`` of the (C, ...) leaf ``like``."""
+    s = like.shape[1:]
+    if len(s) < 2:
+        return v.reshape(s)
+    return v.reshape(s[:-2] + (s[-1], s[-2])).swapaxes(-1, -2)
+
+
+def flatten_rows(tree, width=None):
+    """A pytree with a leading row dim C as one (C, width) f32 matrix: the
+    leaves in `_leaf_order`, each as `_to_rows` lays it out, then zeros up
+    to ``width`` (default: no tail).  `unflatten_row` is its inverse for
+    one row."""
+    leaves = jax.tree.leaves(tree)
+    cols = [_to_rows(leaves[i]).astype(jnp.float32)
+            for i in _leaf_order(leaves)]
+    tail = (width or 0) - sum(x.shape[1] for x in cols)
+    if tail > 0:
+        cols.append(jnp.zeros((cols[0].shape[0], tail), jnp.float32))
+    return jnp.concatenate(cols, axis=1)
+
+
+def unflatten_row(vec, like):
+    """One row of `flatten_rows` (any zero tail ignored) back into the
+    pytree structure of ``like`` without its leading row dim."""
+    leaves, treedef = jax.tree.flatten(like)
+    out, off = [None] * len(leaves), 0
+    for i in _leaf_order(leaves):
+        x = leaves[i]
         n = x[0].size
-        out.append(vec[off:off + n].reshape(x.shape[1:]).astype(x.dtype))
+        out[i] = _from_row(vec[off:off + n], x).astype(x.dtype)
         off += n
     return jax.tree.unflatten(treedef, out)
 
@@ -42,9 +76,9 @@ def trust_aggregate_tree(client_params, weights, mask=None, *,
     """Eqn 6 over a pytree with leading client dim, via the Pallas kernel.
     ``mask`` (C,) selects valid rows (padded fixed-shape cluster rounds)."""
     interpret = _interpret(interpret)
-    flat, leaves, treedef = _flatten_rows(client_params)
-    agg = trust_aggregate(flat, weights, mask, interpret=interpret)
-    return _unflatten_row(agg, leaves, treedef)
+    agg = trust_aggregate(flatten_rows(client_params), weights, mask,
+                          interpret=interpret)
+    return unflatten_row(agg, client_params)
 
 
 def trust_aggregate_global_tree(client_params, weights, mask, cluster_stack,
@@ -53,12 +87,22 @@ def trust_aggregate_global_tree(client_params, weights, mask, cluster_stack,
     plus the stacked cluster parameters (leading dim n_clusters) -> the
     staleness-weighted global model, in one kernel pass.  ``c`` is the
     (traced) cluster whose Eqn-6 aggregate replaces its stack row."""
+    return trust_aggregate_global_flat(
+        flatten_rows(client_params), weights, mask, cluster_stack,
+        global_weights, c, interpret=interpret)
+
+
+def trust_aggregate_global_flat(updates_flat, weights, mask, cluster_stack,
+                                global_weights, c, *, interpret=None):
+    """`trust_aggregate_global_tree` with the member updates already one
+    (C, W) matrix, ``flatten_rows(client_params, W)``.  Built at
+    ``padded_width(C + n_clusters, N)`` columns it streams into the kernel
+    as it is; the cluster stack is flattened to the same W here."""
     interpret = _interpret(interpret)
-    upd_flat, _, _ = _flatten_rows(client_params)
-    stack_flat, leaves, treedef = _flatten_rows(cluster_stack)
-    glob = trust_aggregate_global(upd_flat, weights, mask, stack_flat,
+    stack_flat = flatten_rows(cluster_stack, updates_flat.shape[1])
+    glob = trust_aggregate_global(updates_flat, weights, mask, stack_flat,
                                   global_weights, c, interpret=interpret)
-    return _unflatten_row(glob, leaves, treedef)
+    return unflatten_row(glob, cluster_stack)
 
 
 def attention(q, k, v, *, window=0, softcap=0.0, bq=256, bk=256,

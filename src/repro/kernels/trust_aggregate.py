@@ -54,6 +54,16 @@ def lane_block(rows: int, n: int) -> int:
     return max(128, min(MAX_BLOCK, fit, -(-n // 128) * 128))
 
 
+def padded_width(rows: int, n: int) -> int:
+    """The width a (rows, n) stream is tiled at: n rounded up to whole
+    `lane_block(rows, n)` blocks.  An operand already this wide is read as
+    it is, with no pad (the block of ``padded_width(rows, n)`` columns is
+    the block of ``n``), so a caller that builds its matrix at this width
+    saves the kernel wrapper a copy."""
+    block = lane_block(rows, n)
+    return -(-n // block) * block
+
+
 def _kernel(w_ref, x_ref, o_ref):
     # x_ref: (C, block); w_ref: (C, 1); o_ref: (1, block) — a 2-D output
     # row, so a vmapped call's (batch, 1, block) blocks stay tileable
@@ -131,17 +141,18 @@ def trust_aggregate_global(updates_flat, weights, mask, stack_flat,
     all inside one grid pass over N.  Returns the (N,) global vector (the
     async-pull engine writes it back to both ``global_params`` and row
     ``c`` of the stack, so the intermediate Eqn-6 aggregate never
-    round-trips through HBM).
+    round-trips through HBM).  Operands already ``padded_width(C + B, N)``
+    wide stream as they are; narrower ones are zero-padded to it here.
     """
     C, N = updates_flat.shape
     B, Ns = stack_flat.shape
     assert Ns == N, (Ns, N)
     block = lane_block(C + B, N)
-    pad = (-N) % block
+    Np = padded_width(C + B, N)
+    pad = Np - N
     if pad:
         updates_flat = jnp.pad(updates_flat, ((0, 0), (0, pad)))
         stack_flat = jnp.pad(stack_flat, ((0, 0), (0, pad)))
-    Np = N + pad
     col = lambda r: pl.BlockSpec((r, 1), lambda i: (0, 0))
     out = pl.pallas_call(
         _global_kernel, grid=(Np // block,),

@@ -77,3 +77,47 @@ def test_vmapped_trust_aggregate_global_compiles(one_chip):
         _spec(one_chip, (P, C)), _spec(one_chip, (P, C), jnp.bool_),
         _spec(one_chip, (P, B, N)), _spec(one_chip, (P, B)),
         _spec(one_chip, (P,), jnp.int32))
+
+
+def test_scanned_round_builds_one_member_matrix(one_chip, monkeypatch):
+    """The paper's §V round, compiled whole for the chip: the member
+    matrix is one buffer at the trust kernel's padded width (no (M, N)
+    buffer beside it, no pad in front of the kernel), and no member-sized
+    layout copy of a weight stack feeds it (the flat columns hold each
+    weight matrix with its last two axes swapped, as the chip stores it)."""
+    import re
+
+    import numpy as np
+
+    import repro.api as api
+    import repro.kernels.ops as ops
+    from repro.data import make_classification
+    from repro.kernels.trust_aggregate import padded_width
+
+    monkeypatch.setattr(ops, "_interpret", lambda flag: False)
+    data = make_classification(jax.random.PRNGKey(0), n=1024, dim=784)
+    spec = api.FederationSpec(
+        seed=0, local_batch=64, execution="scanned",
+        fleet=api.FleetSpec(n_devices=16),
+        clustering=api.ClusteringSpec(n_clusters=4),
+        controller=api.ControllerSpec("fixed", {"a": 2}),
+        task=api.TaskSpec("mlp", {"hidden": 200, "n_classes": 10}))
+    eng = api.Federation.from_spec(
+        spec, data=data,
+        parts=np.array_split(np.arange(1024), 16)).engine
+    pol = eng.controller.scan_policy()
+    args = (eng.state, eng._scan_times, pol.state, eng._scan_energy_start(),
+            eng.tables)
+    specs = jax.tree.map(
+        lambda x: _spec(one_chip, jnp.shape(x), jnp.asarray(x).dtype), args)
+    txt = eng._build_scan_fn(1, pol).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in txt
+
+    M = eng.tables.member_table.shape[1]
+    W = padded_width(M + 4, N)
+    ops_of = lambda op: re.findall(                              # noqa: E731
+        rf"= f32\[{M},([0-9,]+)\]\S* {op}\(", txt)
+    assert {w for w in ops_of("dynamic-update-slice")
+            if int(w.split(",")[-1]) >= N} == {str(W)}
+    assert ops_of("pad") == []
+    assert [s for s in ops_of("copy") if s.count(",")] == []
