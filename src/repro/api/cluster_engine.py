@@ -14,9 +14,10 @@ jit the SPMD partitioner carves up:
 
   * replicated pre-work — RNG splits, the Alg.-2 tolerance bound — runs on
     every shard from replicated scalars (bit-identical math, no traffic);
-  * the owning shard runs the *parent's* member round (batch gather, local
-    SGD, Eqns 4-5 trust, Eqn-6 aggregation, energy) under a `lax.cond`,
-    reading its member block with `dynamic_slice`; non-owners skip;
+  * the owning shard runs the *parent's* member phase (its helpers: batch
+    gather, local SGD, Eqns 4-5 trust, Eqn-6 aggregation, energy) under a
+    `lax.cond`, with a member reader that takes its slot block by
+    `dynamic_slice`; non-owners skip;
   * exactly **two** collectives cross shards per round: one `psum` of a
     packed scalar/metrics vector (consumed energy, round loss, the drop
     flag, the straggle factor, the Eqn-19 normalizer, the per-cluster
@@ -70,15 +71,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.control import policy as ctl_policy
 from repro.control import queue as ctl_queue
-from repro.core.clustering import tolerance_bound
-from repro.core.energy import round_energy
-from repro.core.envs import OBS_DIM
-from repro.core.trust import (belief, gradient_diversity, learning_quality,
-                              trust_weights, update_reputation)
-from repro.core.twin import TwinState, calibrate, calibrated_freq
-from repro.data.federated import sample_member_batch
+from repro.core.twin import MEMBER_FILLS, TwinState, calibrate, calibrated_freq
 
-from .engine import DeviceScaleEngine, FleetState, _flat_members
+from .engine import DeviceScaleEngine, FleetState, scan_step
 from .placement import shard_map_placement
 from .spec import ShardingSpec
 
@@ -88,12 +83,6 @@ _STALE_BASE = jnp.e / 2         # Eqn-19 decay base (trust.staleness_weights)
 _EPS = 1e-8                     # its normalizer epsilon
 # FleetState fields sharded over the mesh axis; the rest replicate
 _SHARDED_FIELDS = ("twins", "rep", "channel", "cluster_params", "cluster_ts")
-# neutral member-view fills (twin.member_view): sentinel/dropped slots must
-# read exactly what the parent's gather-with-fill produces, not whatever the
-# padding slot carries (e.g. its alpha tally, which drifts +1 per round)
-_TWIN_FILLS = TwinState(loss=0.0, freq=1.0, freq_dev=0.0, dev_estimate=0.0,
-                        energy=0.0, data_size=1.0, alpha=1.0, beta=0.0,
-                        router_entropy=0.0)
 
 
 class ClusterMajorEngine(DeviceScaleEngine):
@@ -259,9 +248,8 @@ class ClusterMajorEngine(DeviceScaleEngine):
         def perm(x, fill):
             return jnp.asarray(x).at[oos].get(mode="fill", fill_value=fill)
 
-        tw = TwinState(*[perm(getattr(fleet.twins, f),
-                              getattr(_TWIN_FILLS, f))
-                         for f in TwinState._fields])
+        tw = TwinState(*[perm(x, fill)
+                         for x, fill in zip(fleet.twins, MEMBER_FILLS)])
         padc = self._C_pad - self._C
 
         def pad_c(l):
@@ -302,6 +290,17 @@ class ClusterMajorEngine(DeviceScaleEngine):
     # ------------------------------------------------------------------ #
     # the per-shard round (traced under shard_map)
     # ------------------------------------------------------------------ #
+    def _slot_reader(self, lo):
+        """The member phase's ``read`` on this layout: the cluster's slot
+        block at ``lo``, masked to the fills a gather by original id gives
+        (not what a padding slot carries, e.g. its alpha tally, which
+        drifts +1 a round)."""
+        def read(leaf, fill, members, mask):
+            del members
+            sl = jax.lax.dynamic_slice(leaf, (lo,), (self._S,))
+            return jnp.where(mask, sl, fill)
+        return read
+
     def _cm_round_local(self, state, ftbl, ch3, c, a_raw,
                         oos_l, misb_l, mskslot_l, validc_l, tables):
         """One cluster round, shard-local: the parent `_fleet_round` split
@@ -309,7 +308,6 @@ class ClusterMajorEngine(DeviceScaleEngine):
         psums.  Returns (state', ftbl', ch3', metrics)."""
         del ch3                         # consumed by the caller's next obs
         spec = self.spec
-        task = self.task
         fm = self.faults
         S, C_loc = self._S, self._C_loc
         ax = self._ax
@@ -317,101 +315,38 @@ class ClusterMajorEngine(DeviceScaleEngine):
         cl = jnp.clip(c - g * C_loc, 0, C_loc - 1)   # local cluster row
         lo = cl * S                                   # local slot offset
         mine = (c >= g * C_loc) & (c < (g + 1) * C_loc)
+        eng = self._reading(tables)
 
         # --- replicated pre-work: exact parent RNG stream + Alg.-2 bound
-        if fm.active:
-            key, kb, ke, kc2, kdp, kflt = jax.random.split(state.key, 6)
-        else:
-            key, kb, ke, kc2, kdp = jax.random.split(state.key, 5)
-            kflt = None
-        a_req = jnp.clip(jnp.asarray(a_raw), 1, self._n_actions)
-        # max over the *real* clusters only (sentinel table rows hold 1.0)
-        t_ref = a_req.astype(jnp.float32) / jnp.maximum(
-            jnp.max(ftbl[:self._C]), 1e-6)
-        alpha = jnp.minimum(
-            1.0, spec.clustering.alpha0 +
-            spec.clustering.alpha_growth * state.round.astype(jnp.float32))
-        a = tolerance_bound(a_req, ftbl[c], t_ref, alpha)
-        a = jnp.clip(a, 1, self._n_actions)
+        # (the max runs over the *real* clusters: sentinel rows hold 1.0)
+        keys = eng._round_keys(state.key)
+        a = eng._bounded_action(a_raw, ftbl[:self._C], c, state.round)
 
-        def tslice(leaf, fill, mask):
-            sl = jax.lax.dynamic_slice(leaf, (lo,), (S,))
-            return jnp.where(mask, sl, fill)
-
-        # --- owner phase: the parent's member round, verbatim math.  The
-        # full-fleet `FleetTables` (member/partition/data/attackers) ride
-        # in replicated, so gathers by *original* id are identical to the
-        # parent's; only sharded FleetState leaves read through
-        # dynamic_slice at the cluster's slot block.
+        # --- owner phase: the parent's member round.  The full-fleet
+        # `FleetTables` (member/partition/data/attackers) ride in
+        # replicated, so gathers by *original* id are identical to the
+        # parent's; only sharded FleetState leaves read through the slot
+        # reader.
         def owner(_):
-            members = tables.member_table[c]
-            mask = tables.member_mask[c]
-            if fm.may_drop:
-                mask = fm.drop_mask(kflt, mask, members)
-                members = jnp.where(mask, members, self._sentinel)
-            mask_f = mask.astype(jnp.float32)
-            cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
-
-            sel = sample_member_batch(kb, tables.part_idx, tables.part_len,
-                                      members, spec.local_batch)
-            x = tables.x[sel]
-            y = tables.y[sel]
-            if fm.may_poison:
-                x = fm.poison_inputs(kflt, x, members)
-            mal_m = tables.malicious.at[members].get(mode="fill",
-                                                     fill_value=0.0)
-            y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
-            batch = {"x": x, "y": y}
-
-            cur_row = jax.tree.map(
-                lambda l: jax.lax.dynamic_index_in_dim(l, cl, 0,
-                                                       keepdims=False),
-                state.cluster_params)
-            stacked = jax.tree.map(
-                lambda l: jnp.broadcast_to(l[None], (S,) + l.shape),
-                cur_row)
-            new = task.local_train(stacked, batch, spec.lr, a)
-            if fm.may_corrupt:
-                new = fm.corrupt_updates(kflt, new, stacked, members)
-
-            _, upd_flat = _flat_members(new, cur_row)
-            q = learning_quality(upd_flat, mask)
-            div = gradient_diversity(upd_flat, mask)
-            tw_m = TwinState(*[
-                tslice(getattr(state.twins, f), getattr(_TWIN_FILLS, f),
-                       mask) for f in TwinState._fields])
-            if fm.may_spike:
-                tw_m = fm.spike_twins(kflt, tw_m, mask, members)
-            b = belief(tw_m, q, spec.channel.pkt_fail, div)
-            rep_m = update_reputation(
-                tslice(state.rep, 1.0, mask), b,
-                spec.channel.pkt_fail, spec.iota)
-            w = trust_weights(rep_m, mask)
-            if spec.privacy.clip > 0.0:
-                from repro.core.privacy import dp_aggregate
-                agg = dp_aggregate(
-                    kdp, new, cur_row,
-                    w if spec.aggregator.kind == "trust" else mask_f / cnt,
-                    spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
-            else:
-                # Eqn 6 as the spec asks: the masked Pallas kernel when
-                # use_kernel is on, the jnp rule otherwise
-                agg = self.aggregator(new, w, mask)
-
-            losses = task.losses(new, batch)
-            true_freq = tslice(state.twins.freq + state.twins.freq_dev,
-                               1.0, mask)
-            ch_m = tslice(state.channel, 0, mask)
-            e = round_energy(a.astype(jnp.float32), true_freq, ch_m,
-                             ke, members=members) * mask_f
+            read = self._slot_reader(lo)
+            members, mask, mask_f, cnt, empty = eng._member_mask(
+                keys.flt, tables.member_table[c], tables.member_mask[c])
+            new, batch, cur, _, rep_m, w = eng._member_update(
+                state, cl, members, mask, a, keys, read, None)
+            # Eqn 6 as the spec asks: the masked Pallas kernel when
+            # use_kernel is on, the jnp rule otherwise
+            agg = eng._cluster_aggregate(new, w, mask, cur, keys.dp, mask_f,
+                                         cnt)
+            losses, e = eng._member_cost(state, new, batch, a, members, mask,
+                                         mask_f, keys.energy, read)
             # the straggle *factor* (straggle() multiplies its dur arg, so
             # dur=1 extracts it); applied post-psum as dur * factor — the
             # exact product the parent computes
-            stretch = (fm.straggle(kflt, jnp.float32(1.0), mask,
-                                    members)
+            stretch = (fm.straggle(keys.flt, jnp.float32(1.0), mask,
+                                   members)
                        if fm.may_straggle else jnp.float32(1.0))
-            empty = ((jnp.sum(mask_f) < 0.5).astype(jnp.float32)
-                     if fm.may_drop else jnp.float32(0.0))
+            empty = (empty.astype(jnp.float32) if fm.may_drop
+                     else jnp.float32(0.0))
             return {"agg": agg, "losses": losses, "e": e, "rep_m": rep_m,
                     "maskd": mask_f, "consumed": jnp.sum(e),
                     "loss": jnp.sum(losses * mask_f) / cnt,
@@ -444,8 +379,9 @@ class ClusterMajorEngine(DeviceScaleEngine):
         # identical-rows channel: every shard reproduces the parent's
         # original-order full-fleet draw, then gathers its own slots
         new_ch = jax.random.categorical(
-            kc2, jnp.broadcast_to(jnp.log(self._trans[0] + 1e-12),
-                                  (self._n, 3)), axis=-1)
+            keys.channel,
+            jnp.broadcast_to(jnp.log(self._trans[0] + 1e-12), (self._n, 3)),
+            axis=-1)
         channel_l = new_ch.at[oos_l].get(mode="fill", fill_value=0)
 
         rnd = state.round + 1
@@ -524,7 +460,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
         new_state = FleetState(
             twins=tw, rep=rep_new, channel=channel_l, cluster_params=cp2,
             global_params=gparams, cluster_ts=ts_new, queue=queue,
-            round=rnd, key=key)
+            round=rnd, key=keys.key)
         metrics = {"a": a, "dur": dur, "consumed": consumed,
                    "loss": loss_m}
         return new_state, ftbl_new, ch3_new, metrics
@@ -535,26 +471,18 @@ class ClusterMajorEngine(DeviceScaleEngine):
     def _cm_feats_local(self, state, ftbl, ch3, c, mskslot_l, needs_obs,
                         tables):
         """Parent `_ctl_features` + `_scan_obs` over the owner's slot
-        block; one (4,) psum replicates the scalars (+zeros: exact)."""
+        block; one (4,) psum replicates the scalars (+zeros: exact).  The
+        observation is None unless ``needs_obs``."""
         S, C_loc = self._S, self._C_loc
         g = jax.lax.axis_index(self._ax)
         cl = jnp.clip(c - g * C_loc, 0, C_loc - 1)
         lo = cl * S
         mine = (c >= g * C_loc) & (c < (g + 1) * C_loc)
-        tw = state.twins
 
         def owner(_):
             mask = jax.lax.dynamic_slice(mskslot_l, (lo,), (S,))
-            mask_f = mask.astype(jnp.float32)
-            cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
-            loss_s = jax.lax.dynamic_slice(tw.loss, (lo,), (S,))
-            loss = jnp.sum(jnp.where(mask, loss_s, 0.0)) / cnt
-            loss = jnp.nan_to_num(loss, nan=0.0, posinf=2.3)
-            f_s = jax.lax.dynamic_slice(calibrated_freq(tw), (lo,), (S,))
-            mean_freq = jnp.sum(jnp.where(mask, f_s, 0.0)) / cnt
-            ch_s = jax.lax.dynamic_slice(state.channel, (lo,), (S,))
-            good = jnp.sum(jnp.where(
-                mask, (ch_s == 0).astype(jnp.float32), 0.0)) / cnt
+            loss, mean_freq, good = self._member_feats(
+                state, None, mask, self._slot_reader(lo))
             if needs_obs:
                 row = jax.tree.map(
                     lambda l: jax.lax.dynamic_index_in_dim(
@@ -570,13 +498,9 @@ class ClusterMajorEngine(DeviceScaleEngine):
             self._ax)
         feats = {"cluster_loss": vec[0], "mean_freq": vec[1],
                  "channel_good_frac": vec[2], "cluster_freq": ftbl[c]}
-        if needs_obs:
-            obs48 = ctl_policy.deploy_obs(
-                vec[0], state.queue,
-                state.round.astype(jnp.float32) / 100.0, vec[3],
-                state.round % 10, ch3, vec[1])
-        else:
-            obs48 = jnp.zeros((OBS_DIM,), jnp.float32)
+        obs48 = (ctl_policy.deploy_obs(
+            vec[0], state.queue, state.round.astype(jnp.float32) / 100.0,
+            vec[3], state.round % 10, ch3, vec[1]) if needs_obs else None)
         return feats, obs48
 
     # ------------------------------------------------------------------ #
@@ -661,33 +585,22 @@ class ClusterMajorEngine(DeviceScaleEngine):
 
         def local(state, times, ctl, energy, ftbl, ch3,
                   oos_l, misb_l, mskslot_l, validc_l, tables):
-            def body(carry, _):
-                state, times, ctl, energy, ftbl, ch3 = carry
-                c = jnp.argmin(times).astype(jnp.int32)
-                t = times[c]
-                with jax.named_scope("fl.control"):
-                    feats, obs48 = self._cm_feats_local(
-                        state, ftbl, ch3, c, mskslot_l, pol.needs_obs,
-                        tables)
-                    cobs = ctl_policy.CtlObs(
-                        round=state.round, cluster=c, queue=state.queue,
-                        cluster_loss=feats["cluster_loss"],
-                        cluster_freq=feats["cluster_freq"],
-                        mean_freq=feats["mean_freq"],
-                        channel_good_frac=feats["channel_good_frac"],
-                        energy_used=energy, dqn_obs=obs48)
-                    a_raw, ctl = pol.step(ctl, cobs)
+            def features(state, c, extra, needs_obs):
+                ftbl, ch3 = extra
+                return self._cm_feats_local(state, ftbl, ch3, c, mskslot_l,
+                                            needs_obs, tables)
+
+            def round_fn(state, c, a_raw, extra):
+                ftbl, ch3 = extra
                 state, ftbl, ch3, m = self._cm_round_local(
                     state, ftbl, ch3, c, a_raw,
                     oos_l, misb_l, mskslot_l, validc_l, tables)
-                times = times.at[c].set(t + m["dur"])
-                energy = energy + m["consumed"]
-                ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
-                      "consumed": m["consumed"], "loss": m["loss"]}
-                return (state, times, ctl, energy, ftbl, ch3), ys
+                return state, (ftbl, ch3), m
 
-            return jax.lax.scan(body, (state, times, ctl, energy, ftbl,
-                                       ch3), None, length=K)
+            return jax.lax.scan(
+                lambda carry, _: scan_step(carry, features, round_fn,
+                                           pol.step, pol.needs_obs),
+                (state, times, ctl, energy, ftbl, ch3), None, length=K)
 
         ys_specs = {k: P() for k in ("t", "cluster", "a", "dur",
                                      "consumed", "loss")}
@@ -762,7 +675,7 @@ class ClusterMajorEngine(DeviceScaleEngine):
 
     def obs_state_summary(self) -> dict:
         """Telemetry state summary, masked to real device slots: sentinel
-        slots (cluster-major padding) carry the `_TWIN_FILLS` values and
+        slots (cluster-major padding) carry the `MEMBER_FILLS` values and
         would skew the reputation stats if reduced over naively."""
         if self._obs_summary_fn is None:
             def summarize(state, valid):

@@ -68,8 +68,7 @@ Fused and reference modes consume the same RNG streams and the same
 fixed-shape math, so their traces match at a fixed seed — bit for bit on
 scheduling, counters and accuracies; to the last ulp on float reductions,
 where XLA's fused (FMA-contracted) form may differ from eager dispatch
-(tests/test_api.py::test_fused_round_parity_with_reference) — and
-benchmarks/engine_bench.py measures the fusion speedup between them.
+(tests/test_api.py::test_fused_round_parity_with_reference).
 One *statistical* change from the pre-refactor engine: batches are always
 sampled with replacement (`sample_member_batch`'s fixed-shape randint);
 the old per-member loop sampled without replacement when a shard held at
@@ -102,8 +101,8 @@ from repro.core.envs import OBS_DIM
 from repro.core.trust import (belief, gradient_diversity, learning_quality,
                               staleness_weights, time_weighted_average,
                               trust_weights, update_reputation)
-from repro.core.twin import (calibrate, calibrated_freq, init_twins,
-                             member_view, observe_round_members,
+from repro.core.twin import (MEMBER_FILLS, calibrate, calibrated_freq,
+                             init_twins, observe_round_members,
                              sample_deviation, TwinState)
 from repro.data.federated import padded_partition, sample_member_batch
 from repro.faults import FaultModel
@@ -141,6 +140,58 @@ def _flat_members(new, row, width=None):
     flat_row = flatten_rows(jax.tree.map(lambda l: l[None], row), width)
     n = sum(l.size for l in jax.tree.leaves(row))
     return flat_new, (flat_new - flat_row)[:, :n]
+
+
+def _read_by_id(leaf, fill, members, mask):
+    """The members' rows of a fleet-axis ``leaf`` in original device order:
+    a gather by id in which the padding sentinel (and so a dropped member)
+    reads ``fill``."""
+    del mask
+    return leaf.at[members].get(mode="fill", fill_value=fill)
+
+
+class _RoundKeys(NamedTuple):
+    """One round's RNG streams; ``flt`` is None without a fault model."""
+    key: Any            # the state's key for the next round
+    batch: Any
+    energy: Any
+    channel: Any
+    dp: Any
+    flt: Any
+
+
+def scan_step(carry, features, round_fn, policy, needs_obs: bool):
+    """One round of a scanned run: the step every fleet engine scans.
+
+    ``carry`` is ``(state, times, ctl, energy, *extra)``.  The cluster
+    with the earliest event time runs: ``features(state, c, extra,
+    needs_obs)`` gives the controller features and the DQN observation
+    (None when not computed: the policy then reads zeros), ``policy(ctl,
+    CtlObs)`` picks the raw action, and ``round_fn(state, c, a_raw,
+    extra)`` runs the round, returning ``(state, extra, metrics)``."""
+    state, times, ctl, energy, *extra = carry
+    # the event heap pops min (t, c); argmin breaks ties on the first
+    # (lowest) cluster index exactly as tuple order does
+    c = jnp.argmin(times).astype(jnp.int32)
+    t = times[c]
+    with jax.named_scope("fl.control"):
+        feats, obs48 = features(state, c, extra, needs_obs)
+        if obs48 is None:
+            obs48 = jnp.zeros((OBS_DIM,), jnp.float32)
+        cobs = ctl_policy.CtlObs(
+            round=state.round, cluster=c, queue=state.queue,
+            cluster_loss=feats["cluster_loss"],
+            cluster_freq=feats["cluster_freq"],
+            mean_freq=feats["mean_freq"],
+            channel_good_frac=feats["channel_good_frac"],
+            energy_used=energy, dqn_obs=obs48)
+        a_raw, ctl = policy(ctl, cobs)
+    state, extra, m = round_fn(state, c, a_raw, extra)
+    times = times.at[c].set(t + m["dur"])
+    energy = energy + m["consumed"]
+    ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
+          "consumed": m["consumed"], "loss": m["loss"]}
+    return (state, times, ctl, energy, *extra), ys
 
 
 @runtime_checkable
@@ -250,9 +301,9 @@ class DeviceScaleEngine:
         sizes = jnp.asarray([len(p) for p in parts], jnp.float32)
         twins = twins._replace(data_size=sizes)
         if assign is None:
-            # kc is always split so an assignment override (capacity
-            # benchmarks skip the O(n*C) k-means) leaves every other
-            # stream in the engine untouched
+            # kc is always split so an assignment override (a caller that
+            # skips the O(n*C) k-means) leaves every other stream in the
+            # engine untouched
             assign, _ = cluster_devices(kc, twins, C)
         self.assign = ensure_nonempty(np.asarray(assign), C)
         member_table, member_mask = padded_membership(self.assign, C)
@@ -559,133 +610,51 @@ class DeviceScaleEngine:
         SGD, Eqns 4-5 trust, Eqn-6 aggregation (masked Pallas kernel),
         optional DP, Eqns 7-8 energy, twin observe/calibrate, channel step,
         and the Eqn-19 global aggregate.  ``members``/``mask`` are a
-        fixed-shape member slice (padded with the sentinel n, or exact)."""
+        fixed-shape member slice (padded with the sentinel n, or exact).
+        The member phase runs in the helpers below, which the cluster-major
+        engine calls too; this method adds the scatters into the fleet and
+        Eqn 19."""
         spec = self.spec
-        task = self.task
         fm = self.faults
         twins = state.twins
         with jax.named_scope("fl.batch"):
-            # an active fault model splits one extra key; inert specs keep
-            # the exact pre-fault stream (and compile the exact pre-fault
-            # program — every fm.may_* gate below is a static Python bool)
-            if fm.active:
-                key, kb, ke, kc2, kdp, kflt = jax.random.split(state.key, 6)
-            else:
-                key, kb, ke, kc2, kdp = jax.random.split(state.key, 5)
-                kflt = None
-            if fm.may_drop:
-                # dropped members leave the padded mask AND become the padding
-                # sentinel, so every downstream gather fills neutrally and
-                # every scatter (reputation, twin observe) drops them — the
-                # round treats a dropped device exactly like a padding slot
-                mask = fm.drop_mask(kflt, mask, members)
-                members = jnp.where(mask, members, self._sentinel)
-            mask_f = mask.astype(jnp.float32)
-            cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
-            # a fully-dropped cluster skips its event: state carries unchanged
-            # (the degenerate all-padding aggregate would zero the cluster row)
-            empty = jnp.sum(mask_f) < 0.5 if fm.may_drop else None
+            keys = self._round_keys(state.key)
+            members, mask, mask_f, cnt, empty = self._member_mask(
+                keys.flt, members, mask)
+            a = self._bounded_action(a_raw, self._cluster_freq_table(twins),
+                                     c, state.round)
 
-            # --- controller choice capped by the Alg.-2 tolerance bound.
-            # T_m is the fastest cluster's time for the *requested* local phase
-            # (a_req / f_max, the convention test_tolerance_bound_caps_slow_
-            # clusters pins); slower clusters get proportionally fewer steps,
-            # scaling in as alpha grows.  The old reference (one step of the
-            # fastest cluster) made the cap floor to 1 for every cluster at
-            # alpha <= 1, silencing every frequency controller.
-            cluster_freq = self._cluster_freq_table(twins)
-            a_req = jnp.clip(jnp.asarray(a_raw), 1, self._n_actions)
-            t_ref = a_req.astype(jnp.float32) / jnp.maximum(
-                jnp.max(cluster_freq), 1e-6)
-            alpha = jnp.minimum(
-                1.0, spec.clustering.alpha0 +
-                spec.clustering.alpha_growth * state.round.astype(jnp.float32))
-            a = tolerance_bound(a_req, cluster_freq[c], t_ref, alpha)
-            a = jnp.clip(a, 1, self._n_actions)
-
-            # --- local batches from the padded partition matrix
-            tb = self.tables
-            with jax.named_scope("fl.gather"):
-                sel = sample_member_batch(kb, tb.part_idx, tb.part_len,
-                                          members, spec.local_batch)
-                x = tb.x[sel]
-                y = tb.y[sel]
-            if fm.may_poison:
-                # poisons the sampled features before they enter local_train;
-                # for reconstruction tasks (corrupt_labels a no-op) this is the
-                # only attack surface that touches the loss
-                x = fm.poison_inputs(kflt, x, members)
-            mal_m = tb.malicious.at[members].get(mode="fill", fill_value=0.0)
-            y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
-            batch = {"x": x, "y": y}
-
-        with jax.named_scope("fl.local_sgd"):
-            # --- a local steps on every member (vmap), from the cluster model
-            m_dim = members.shape[0]
-            cur = jax.tree.map(lambda l: l[c], state.cluster_params)
-            stacked = jax.tree.map(
-                lambda l: jnp.broadcast_to(l, (m_dim,) + l.shape), cur)
-            new = task.local_train(stacked, batch, spec.lr, a)
-            if fm.may_corrupt:
-                # Byzantine members replace their honest deltas *before* the
-                # trust chain sees them — Eqns 4-5 must earn their keep
-                new = fm.corrupt_updates(kflt, new, stacked, members)
-
-        # --- trust update (Eqns 4-5) & pluggable aggregation (Eqn 6)
         # with a fused-global aggregator the Eqn-6 aggregate never leaves
         # the kernel (see the Eqn-19 block below); DP needs the bare
         # aggregate to clip against, so it keeps the two-step path
         fuse_global = self._fused_global and spec.privacy.clip <= 0.0
-        with jax.named_scope("fl.flatten"):
-            # the kernel's width (member rows + the cluster stack's rows):
-            # it then streams flat_new with no pad of its own
-            stack = jax.tree.leaves(state.cluster_params)
-            width = (padded_width(m_dim + stack[0].shape[0],
-                                  sum(l[0].size for l in stack))
-                     if fuse_global else None)
-            flat_new, upd_flat = _flat_members(new, cur, width)
+        # the kernel's width (member rows + the cluster stack's rows): it
+        # then streams flat_new with no pad of its own
+        stack = jax.tree.leaves(state.cluster_params)
+        width = (padded_width(members.shape[0] + stack[0].shape[0],
+                              sum(l[0].size for l in stack))
+                 if fuse_global else None)
+        new, batch, cur, flat_new, rep_m, w = self._member_update(
+            state, c, members, mask, a, keys, _read_by_id, width)
         with jax.named_scope("fl.trust"):
-            q = learning_quality(upd_flat, mask)
-            div = gradient_diversity(upd_flat, mask)
-            tw_m = member_view(twins, members)
-            if fm.may_spike:
-                # amplified f̂ deviation feeds straight into Eqn 4's
-                # 1/(1+|Δf̂|) normalization
-                tw_m = fm.spike_twins(kflt, tw_m, mask, members)
-            b = belief(tw_m, q, spec.channel.pkt_fail, div)
-            rep_m = update_reputation(
-                state.rep.at[members].get(mode="fill", fill_value=1.0), b,
-                spec.channel.pkt_fail, spec.iota)
             rep = state.rep.at[members].set(rep_m, mode="drop")
-            w = trust_weights(rep_m, mask)
-        with jax.named_scope("fl.aggregate"):
-            if not fuse_global:
-                agg = (self.aggregator(new, w, mask) if self._padded
-                       else self.aggregator(new, w))
-                if spec.privacy.clip > 0.0:
-                    from repro.core.privacy import dp_aggregate
-                    agg = dp_aggregate(
-                        kdp, new, cur,
-                        w if spec.aggregator.kind == "trust" else mask_f / cnt,
-                        spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
+        if not fuse_global:
+            agg = self._cluster_aggregate(new, w, mask, cur, keys.dp, mask_f,
+                                          cnt)
+            with jax.named_scope("fl.aggregate"):
                 cparams = jax.tree.map(
                     lambda L, g: L.at[c].set(g.astype(L.dtype)),
                     state.cluster_params, agg)
 
+        losses, e = self._member_cost(state, new, batch, a, members, mask,
+                                      mask_f, keys.energy, _read_by_id)
         with jax.named_scope("fl.twins"):
-            # --- losses, energy (Eqns 7-8), twins
-            losses = task.losses(new, batch)
-            true_freq = (twins.freq + twins.freq_dev).at[members].get(
-                mode="fill", fill_value=1.0)
-            ch_m = state.channel.at[members].get(mode="fill", fill_value=0)
-            e = round_energy(a.astype(jnp.float32), true_freq, ch_m, ke,
-                             members=members) * mask_f
             consumed = jnp.sum(e)
             twins = observe_round_members(twins, members, losses, e,
-                                          tb.misbehaving)
+                                          self.tables.misbehaving)
             if spec.fleet.calibrate_dt:
                 twins = calibrate(twins)
-            channel = step_channel(kc2, state.channel, self._trans)
+            channel = step_channel(keys.channel, state.channel, self._trans)
 
         with jax.named_scope("fl.aggregate"):
             # --- Eqn 19: staleness-weighted global aggregate (async pull)
@@ -731,15 +700,157 @@ class DeviceScaleEngine:
             dur = a.astype(jnp.float32) / jnp.maximum(
                 self._cluster_freq_table(twins)[c], 1e-6)
             if fm.may_straggle:
-                dur = fm.straggle(kflt, dur, mask, members)
+                dur = fm.straggle(keys.flt, dur, mask, members)
 
         new_state = FleetState(
             twins=twins, rep=rep, channel=channel, cluster_params=cparams,
             global_params=gparams, cluster_ts=ts, queue=queue, round=rnd,
-            key=key)
+            key=keys.key)
         metrics = {"a": a, "dur": dur, "consumed": consumed,
                    "loss": jnp.sum(losses * mask_f) / cnt}
         return new_state, metrics
+
+    # ------------------------------------------------------------------ #
+    # the member phase: one copy for every fleet engine.  ``read(leaf,
+    # fill, members, mask)`` reads the members' rows of a fleet-axis
+    # leaf — by original id here (`_read_by_id`), from the cluster's slot
+    # block in the cluster-major engine — and is the only thing in which
+    # the engines' member math differs.
+    # ------------------------------------------------------------------ #
+    def _round_keys(self, key) -> "_RoundKeys":
+        """The round's RNG streams.  An active fault model splits one extra
+        key; inert specs keep the exact pre-fault stream (and compile the
+        exact pre-fault program — every ``fm.may_*`` gate is a static
+        Python bool)."""
+        if self.faults.active:
+            return _RoundKeys(*jax.random.split(key, 6))
+        return _RoundKeys(*jax.random.split(key, 5), None)
+
+    def _member_mask(self, kflt, members, mask):
+        """The round's member slots after the fault model's dropout.
+
+        Dropped members leave the padded mask AND become the padding
+        sentinel, so every downstream read fills neutrally and every
+        scatter (reputation, twin observe) drops them — the round treats a
+        dropped device exactly like a padding slot.  Returns members, mask,
+        the mask as f32, the member count (at least 1) and ``empty``: a
+        fully-dropped cluster skips its event (the degenerate all-padding
+        aggregate would zero the cluster row); None with dropout off."""
+        fm = self.faults
+        if fm.may_drop:
+            mask = fm.drop_mask(kflt, mask, members)
+            members = jnp.where(mask, members, self._sentinel)
+        mask_f = mask.astype(jnp.float32)
+        cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
+        empty = jnp.sum(mask_f) < 0.5 if fm.may_drop else None
+        return members, mask, mask_f, cnt, empty
+
+    def _bounded_action(self, a_raw, freq, c, rnd):
+        """The controller's choice capped by the Alg.-2 tolerance bound;
+        ``freq`` is the straggler frequency of every cluster.
+
+        T_m is the fastest cluster's time for the *requested* local phase
+        (a_req / f_max, the convention test_tolerance_bound_caps_slow_
+        clusters pins); slower clusters get proportionally fewer steps,
+        scaling in as alpha grows.  The old reference (one step of the
+        fastest cluster) made the cap floor to 1 for every cluster at
+        alpha <= 1, silencing every frequency controller."""
+        cl = self.spec.clustering
+        a_req = jnp.clip(jnp.asarray(a_raw), 1, self._n_actions)
+        t_ref = a_req.astype(jnp.float32) / jnp.maximum(jnp.max(freq), 1e-6)
+        alpha = jnp.minimum(
+            1.0, cl.alpha0 + cl.alpha_growth * rnd.astype(jnp.float32))
+        a = tolerance_bound(a_req, freq[c], t_ref, alpha)
+        return jnp.clip(a, 1, self._n_actions)
+
+    def _member_update(self, state: FleetState, row, members, mask, a, keys,
+                       read, width):
+        """Local batches, ``a`` local SGD steps on every member from the
+        cluster model (row ``row`` of the stack), the flat member matrix at
+        ``width``, and the Eqns 4-5 trust update.
+
+        Returns ``(new, batch, cur, flat_new, rep_m, w)``: the members'
+        models, their batches, the cluster model they started from, the
+        flat member matrix, the members' new reputations and their Eqn-6
+        trust weights."""
+        spec = self.spec
+        task = self.task
+        fm = self.faults
+        tb = self.tables
+        with jax.named_scope("fl.batch"):
+            # --- local batches from the padded partition matrix
+            with jax.named_scope("fl.gather"):
+                sel = sample_member_batch(keys.batch, tb.part_idx,
+                                          tb.part_len, members,
+                                          spec.local_batch)
+                x = tb.x[sel]
+                y = tb.y[sel]
+            if fm.may_poison:
+                # poisons the sampled features before they enter local_train;
+                # for reconstruction tasks (corrupt_labels a no-op) this is
+                # the only attack surface that touches the loss
+                x = fm.poison_inputs(keys.flt, x, members)
+            mal_m = tb.malicious.at[members].get(mode="fill", fill_value=0.0)
+            y = jnp.where(mal_m[:, None] > 0.5, task.corrupt_labels(y), y)
+            batch = {"x": x, "y": y}
+
+        with jax.named_scope("fl.local_sgd"):
+            # --- a local steps on every member (vmap), from the cluster model
+            m_dim = members.shape[0]
+            cur = jax.tree.map(lambda l: l[row], state.cluster_params)
+            stacked = jax.tree.map(
+                lambda l: jnp.broadcast_to(l, (m_dim,) + l.shape), cur)
+            new = task.local_train(stacked, batch, spec.lr, a)
+            if fm.may_corrupt:
+                # Byzantine members replace their honest deltas *before* the
+                # trust chain sees them — Eqns 4-5 must earn their keep
+                new = fm.corrupt_updates(keys.flt, new, stacked, members)
+
+        with jax.named_scope("fl.flatten"):
+            flat_new, upd_flat = _flat_members(new, cur, width)
+        with jax.named_scope("fl.trust"):
+            q = learning_quality(upd_flat, mask)
+            div = gradient_diversity(upd_flat, mask)
+            tw_m = TwinState(*[read(l, fill, members, mask) for l, fill
+                               in zip(state.twins, MEMBER_FILLS)])
+            if fm.may_spike:
+                # amplified f̂ deviation feeds straight into Eqn 4's
+                # 1/(1+|Δf̂|) normalization
+                tw_m = fm.spike_twins(keys.flt, tw_m, mask, members)
+            b = belief(tw_m, q, spec.channel.pkt_fail, div)
+            rep_m = update_reputation(read(state.rep, 1.0, members, mask), b,
+                                      spec.channel.pkt_fail, spec.iota)
+            w = trust_weights(rep_m, mask)
+        return new, batch, cur, flat_new, rep_m, w
+
+    def _cluster_aggregate(self, new, w, mask, cur, kdp, mask_f, cnt):
+        """Eqn 6 over the members' models, or its DP form (clipped updates
+        from ``cur``, plus noise) when the spec sets a clip.  Mask-aware
+        aggregators read the padded member rows with ``mask``; the
+        rank-statistic rules get exact member shapes."""
+        spec = self.spec
+        with jax.named_scope("fl.aggregate"):
+            if spec.privacy.clip > 0.0:
+                from repro.core.privacy import dp_aggregate
+                return dp_aggregate(
+                    kdp, new, cur,
+                    w if spec.aggregator.kind == "trust" else mask_f / cnt,
+                    spec.privacy.clip, spec.privacy.noise, n_clients=cnt)
+            return (self.aggregator(new, w, mask) if self._padded
+                    else self.aggregator(new, w))
+
+    def _member_cost(self, state: FleetState, new, batch, a, members, mask,
+                     mask_f, ke, read):
+        """The members' losses on their batches and their Eqns 7-8 round
+        energies (zero at padding and dropped slots)."""
+        with jax.named_scope("fl.twins"):
+            losses = self.task.losses(new, batch)
+            true_freq = read(state.twins.freq + state.twins.freq_dev, 1.0,
+                             members, mask)
+            ch_m = read(state.channel, 0, members, mask)
+            e = round_energy(a.astype(jnp.float32), true_freq, ch_m, ke,
+                             members=members) * mask_f
+        return losses, e
 
     # ------------------------------------------------------------------ #
     # control plane: per-cluster controller features, computable in-jit
@@ -753,24 +864,32 @@ class DeviceScaleEngine:
         round), the scanned path traced straight into the round scan — so
         host and in-jit ``select`` see identical device math.
         """
+        loss, mean_freq, good = self._member_feats(
+            state, self.tables.member_table[c], self.tables.member_mask[c],
+            _read_by_id)
+        return {"cluster_loss": loss, "mean_freq": mean_freq,
+                "channel_good_frac": good,
+                "cluster_freq": self._cluster_freq_table(state.twins)[c]}
+
+    @staticmethod
+    def _member_feats(state: FleetState, members, mask, read):
+        """A cluster's masked mean twin loss, mean calibrated frequency
+        and share of members in the good channel state, its rows read by
+        ``read`` (the member phase's reader)."""
         twins = state.twins
-        members = self.tables.member_table[c]
-        mask = self.tables.member_mask[c]
         mask_f = mask.astype(jnp.float32)
         cnt = jnp.maximum(jnp.sum(mask_f), 1.0)
 
-        loss_m = twins.loss.at[members].get(mode="fill", fill_value=0.0)
-        loss = jnp.sum(jnp.where(mask, loss_m, 0.0)) / cnt
-        loss = jnp.nan_to_num(loss, nan=0.0, posinf=2.3)
-        f_m = calibrated_freq(twins).at[members].get(mode="fill",
-                                                     fill_value=0.0)
-        mean_freq = jnp.sum(jnp.where(mask, f_m, 0.0)) / cnt
-        ch_m = state.channel.at[members].get(mode="fill", fill_value=1)
-        good = jnp.sum(jnp.where(mask, (ch_m == 0).astype(jnp.float32),
-                                 0.0)) / cnt
-        return {"cluster_loss": loss, "mean_freq": mean_freq,
-                "channel_good_frac": good,
-                "cluster_freq": self._cluster_freq_table(twins)[c]}
+        def mean(leaf, fill):
+            return jnp.sum(jnp.where(mask, read(leaf, fill, members, mask),
+                                     0.0)) / cnt
+
+        loss = jnp.nan_to_num(mean(twins.loss, 0.0), nan=0.0, posinf=2.3)
+        mean_freq = mean(calibrated_freq(twins), 0.0)
+        good = jnp.sum(jnp.where(
+            mask, (read(state.channel, 1, members, mask) == 0).astype(
+                jnp.float32), 0.0)) / cnt
+        return loss, mean_freq, good
 
     def _scan_obs(self, state: FleetState, c, feats) -> jnp.ndarray:
         """The §IV-B DQN observation, pure jnp — one layout for both the
@@ -822,39 +941,30 @@ class DeviceScaleEngine:
     # ------------------------------------------------------------------ #
     # scan-over-rounds: K rounds + in-jit controller in one lax.scan
     # ------------------------------------------------------------------ #
-    def _build_scan_fn(self, K: int, pol: ctl_policy.ScanPolicy):
-        def body(eng, carry):
-            state, times, ctl, energy = carry
-            # the event heap pops min (t, c); argmin breaks ties on the
-            # first (lowest) cluster index exactly as tuple order does
-            c = jnp.argmin(times).astype(jnp.int32)
-            t = times[c]
-            with jax.named_scope("fl.control"):
-                feats = eng._ctl_features(state, c)
-                obs48 = (eng._scan_obs(state, c, feats)
-                         if pol.needs_obs else jnp.zeros((OBS_DIM,),
-                                                         jnp.float32))
-                cobs = ctl_policy.CtlObs(
-                    round=state.round, cluster=c, queue=state.queue,
-                    cluster_loss=feats["cluster_loss"],
-                    cluster_freq=feats["cluster_freq"],
-                    mean_freq=feats["mean_freq"],
-                    channel_good_frac=feats["channel_good_frac"],
-                    energy_used=energy, dqn_obs=obs48)
-                a_raw, ctl = pol.step(ctl, cobs)
-            state, m = eng._fleet_round(
-                state, c, a_raw, eng.tables.member_table[c],
-                eng.tables.member_mask[c])
-            times = times.at[c].set(t + m["dur"])
-            energy = energy + m["consumed"]
-            ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
-                  "consumed": m["consumed"], "loss": m["loss"]}
-            return (state, times, ctl, energy), ys
+    def _scan_features(self, state: FleetState, c, extra, needs_obs):
+        """`scan_step`'s features: `_ctl_features`, and `_scan_obs` when
+        the policy reads the observation."""
+        del extra
+        feats = self._ctl_features(state, c)
+        return feats, (self._scan_obs(state, c, feats) if needs_obs
+                       else None)
 
+    def _scan_round(self, state: FleetState, c, a_raw, extra):
+        """`scan_step`'s round: `_fleet_round` over cluster ``c``'s padded
+        membership row."""
+        state, m = self._fleet_round(state, c, a_raw,
+                                     self.tables.member_table[c],
+                                     self.tables.member_mask[c])
+        return state, extra, m
+
+    def _build_scan_fn(self, K: int, pol: ctl_policy.ScanPolicy):
         def run_k(state, times, ctl, energy, tables):
             eng = self._reading(tables)
-            return jax.lax.scan(lambda carry, _: body(eng, carry),
-                                (state, times, ctl, energy), None, length=K)
+            return jax.lax.scan(
+                lambda carry, _: scan_step(carry, eng._scan_features,
+                                           eng._scan_round, pol.step,
+                                           pol.needs_obs),
+                (state, times, ctl, energy), None, length=K)
 
         jit_kw = dict(donate_argnums=(0,))
         if self.placement.is_sharded:

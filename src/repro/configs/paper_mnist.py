@@ -1,7 +1,7 @@
 """The paper's own experimental model: 784 -> 200 -> 10 MLP on (synthetic)
 MNIST with a 48 x 200 x 10 DQN controlling aggregation frequency (§V).
 
-Not a transformer — used by benchmarks/ and core.mlp; kept in the registry
+Not a transformer — the config of core.mlp's model; kept in the registry
 so `--arch paper-mnist` selects the paper-faithful experiment scale.
 """
 from ..core.dqn import DQNConfig

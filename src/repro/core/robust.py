@@ -1,8 +1,8 @@
 """Robust-aggregation baselines (the literature the paper positions
 against: §II "aggregation strategy").
 
-The paper's trust-weighted aggregation (Eqns 4-6) is compared in
-benchmarks/attack_bench.py against the standard Byzantine-robust rules:
+The paper's trust-weighted aggregation (Eqns 4-6) stands beside the
+standard Byzantine-robust rules, each a registered aggregator:
 
   krum / multi-krum   (Blanchard et al., 2017)
   coordinate median   (Yin et al., 2018)

@@ -77,23 +77,12 @@ def observe_round(twins: TwinState, losses, energies, malicious_mask=None
 # ------------------------------------------------------------------ #
 # fixed-shape member views for the fused FleetState round
 # ------------------------------------------------------------------ #
-def member_view(twins: TwinState, members) -> TwinState:
-    """Gather a (M,) member slice of every twin array, jit-safely.
-
-    ``members`` may hold the out-of-range padding sentinel n; those slots
-    fill with neutral values (alpha=1 so the Eqn-4 interaction ratio stays
-    finite) and must be masked by the caller before any reduction.
-    """
-    def take(x, fill):
-        return x.at[members].get(mode="fill", fill_value=fill)
-
-    return TwinState(
-        loss=take(twins.loss, 0.0), freq=take(twins.freq, 1.0),
-        freq_dev=take(twins.freq_dev, 0.0),
-        dev_estimate=take(twins.dev_estimate, 0.0),
-        energy=take(twins.energy, 0.0), data_size=take(twins.data_size, 1.0),
-        alpha=take(twins.alpha, 1.0), beta=take(twins.beta, 0.0),
-        router_entropy=take(twins.router_entropy, 0.0))
+# what a padding or dropped member slot reads of each twin array; the
+# round masks these slots before any reduction (alpha=1 keeps the Eqn-4
+# interaction ratio finite)
+MEMBER_FILLS = TwinState(loss=0.0, freq=1.0, freq_dev=0.0, dev_estimate=0.0,
+                         energy=0.0, data_size=1.0, alpha=1.0, beta=0.0,
+                         router_entropy=0.0)
 
 
 def observe_round_members(twins: TwinState, members, losses, energies,

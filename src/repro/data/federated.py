@@ -37,11 +37,10 @@ def dirichlet_partition(key, labels, n_clients: int, alpha: float = 0.5,
 def uniform_cycle_partition(n_samples: int, n_devices: int):
     """Round-robin shards: device i owns rows {i, i+n, i+2n, ...}.
 
-    The O(1)-per-device partition the capacity benchmarks use at
-    n_devices >= 10^4, where a dirichlet draw (and its Python list
-    assembly) dominates setup time.  Every shard is non-empty as long as
-    ``n_samples >= n_devices`` — smaller fleets wrap around so row
-    ``i % n_samples`` seeds device i.
+    The O(1)-per-device partition for fleets of n_devices >= 10^4, where
+    a dirichlet draw (and its Python list assembly) dominates setup
+    time.  Every shard is non-empty as long as ``n_samples >= n_devices``
+    — smaller fleets wrap around so row ``i % n_samples`` seeds device i.
     """
     if n_samples >= n_devices:
         return [np.arange(i, n_samples, n_devices, dtype=np.int64)

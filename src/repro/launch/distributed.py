@@ -31,6 +31,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 from typing import List, Optional, Sequence
 
 ENV_COORD = "REPRO_DIST_COORD"            # host:port of process 0
@@ -91,7 +92,8 @@ def spawn_local(argv: Sequence[str], n_procs: int = 2,
     coordinator (process 0).  Blocks until every worker exits and returns
     their `CompletedProcess` results (stdout/stderr captured, text mode);
     the caller asserts on return codes and parses whatever the workers
-    printed.
+    printed.  ``timeout`` bounds the whole job: past it every worker is
+    killed and `subprocess.TimeoutExpired` raised.
     """
     coord = coordinator or f"127.0.0.1:{free_port()}"
     base = dict(os.environ if env is None else env)
@@ -103,13 +105,16 @@ def spawn_local(argv: Sequence[str], n_procs: int = 2,
         procs.append(subprocess.Popen(
             [sys.executable, *argv], env=e, stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True))
+    deadline = time.monotonic() + timeout
     out = []
-    for pid, p in enumerate(procs):
+    for p in procs:
         try:
-            stdout, stderr = p.communicate(timeout=timeout)
+            stdout, stderr = p.communicate(
+                timeout=max(0.0, deadline - time.monotonic()))
         except subprocess.TimeoutExpired:
             for q in procs:
                 q.kill()
+                q.wait()
             raise
         out.append(subprocess.CompletedProcess(p.args, p.returncode,
                                                stdout, stderr))

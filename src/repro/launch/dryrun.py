@@ -106,7 +106,7 @@ def main():
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--out", default="benchmarks/dryrun_results.jsonl")
+    ap.add_argument("--out", default="dryrun_results.jsonl")
     args = ap.parse_args()
 
     archs = all_arch_ids() if args.arch == "all" else [args.arch]

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 
-# v5e hardware constants for the roofline model (benchmarks/roofline.py)
+# v5e hardware constants for the roofline model
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s per chip
 HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link

@@ -46,12 +46,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.api.components import WeightedAggregator
-from repro.api.engine import DeviceScaleEngine
+from repro.api.engine import DeviceScaleEngine, scan_step
 from repro.api.records import FLTrace, RoundRecord
 from repro.api.spec import FederationSpec
 from repro.control import policy as ctl_policy
 from repro.control import queue as ctl_queue
-from repro.core.envs import OBS_DIM
 from repro.faults.model import FaultModel
 
 from .spec import POP_AXIS, PopulationSpec
@@ -402,27 +401,11 @@ class PopulationEngine:
 
         def member_body(state, times, ctl, energy, mp, tables):
             view = self._member_view(mp, tables)
-            c = jnp.argmin(times).astype(jnp.int32)
-            t = times[c]
-            feats = view._ctl_features(state, c)
-            obs48 = (view._scan_obs(state, c, feats) if needs_obs
-                     else jnp.zeros((OBS_DIM,), jnp.float32))
-            cobs = ctl_policy.CtlObs(
-                round=state.round, cluster=c, queue=state.queue,
-                cluster_loss=feats["cluster_loss"],
-                cluster_freq=feats["cluster_freq"],
-                mean_freq=feats["mean_freq"],
-                channel_good_frac=feats["channel_good_frac"],
-                energy_used=energy, dqn_obs=obs48)
-            a_raw, ctl = pol_step(ctl, cobs, mp.get("pol"))
-            state, m = view._fleet_round(
-                state, c, a_raw, tables.member_table[c],
-                tables.member_mask[c])
-            times = times.at[c].set(t + m["dur"])
-            energy = energy + m["consumed"]
-            ys = {"t": t, "cluster": c, "a": m["a"], "dur": m["dur"],
-                  "consumed": m["consumed"], "loss": m["loss"]}
-            return (state, times, ctl, energy), ys
+            return scan_step(
+                (state, times, ctl, energy), view._scan_features,
+                view._scan_round,
+                lambda ctl, cobs: pol_step(ctl, cobs, mp.get("pol")),
+                needs_obs)
 
         vbody = jax.vmap(member_body, in_axes=(0, 0, 0, 0, 0, 0))
         mp = self._mp

@@ -22,7 +22,7 @@ without advancing the checkpoint frontier exhausts ``max_restarts`` and
 raises — a crash-*loop* is a bug, a crash is routine.
 
 CLI: ``python -m repro.serve chaos --run-dir ... --total-segments 4
---kills 2`` (see `__main__.py`); `benchmarks/smoke.sh` runs this in CI.
+--kills 2`` (see `__main__.py`); the CI workflow runs it.
 """
 from __future__ import annotations
 

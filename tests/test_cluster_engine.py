@@ -167,6 +167,22 @@ def test_uneven_membership_pads_logs_and_stays_bit_identical(caplog):
     assert _records(plain.run_scanned(6)) == _records(cm.run_scanned(6))
 
 
+def test_cluster_major_round_carries_the_stage_scopes():
+    """The cluster-major scan runs the unsharded engine's member phase, so
+    its compiled program carries the same stage scopes the per-stage
+    device-time metrics read."""
+    data, parts = _data(seed=37)
+    fed = Federation.from_spec(_spec(37), data=data, parts=parts)
+    assert _cluster_major(fed)
+    eng = fed.engine
+    pol = eng.controller.scan_policy()
+    args = (eng.state, eng._scan_times, pol.state, eng._scan_energy_start(),
+            eng._ftbl, eng._ch3, *eng._statics)
+    hlo = eng._build_scan_fn(2, pol).lower(*args).compile().as_text()
+    for name in ("fl.gather", "fl.local_sgd", "fl.flatten", "fl.trust"):
+        assert f"/{name}/" in hlo, name
+
+
 # --------------------------------------------------------------------- #
 # checkpoints: original device order at the boundary, both directions
 # --------------------------------------------------------------------- #
