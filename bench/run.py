@@ -70,6 +70,16 @@ def peaks(kind: str) -> dict:
     return table[kind]
 
 
+def breakdown(ctx) -> dict:
+    """The traced window's costliest device ops and its idle gaps: by the
+    innermost program (``fl.*``) span where the program's view of the
+    trace loaded, else by the innermost ``bench.*`` span."""
+    from bench import program_trace
+    from bench import trace as bench_trace
+    view = program_trace.of(ctx)
+    return bench_trace.breakdown(view.red if view else ctx["trace"])
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              cfg=None, devices=None, clock=None,
              work_dir: str = "") -> dict:
@@ -154,7 +164,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
            "device": device}
     if red is not None:
         device.update(busy_s=red.busy_s, window_s=red.window_s)
-        out["breakdown"] = bench_trace.breakdown(red)
+        out["breakdown"] = breakdown(ctx)
     out["checks"] = {n: {"value": v, "limit": lim} for n, v, lim in rows}
     shutil.rmtree(run.work_dir, ignore_errors=True)
     return out

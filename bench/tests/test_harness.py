@@ -87,6 +87,12 @@ def test_every_per_layer_metric_moves_a_metric_its_cells_report():
                    if m["name"] != "setup_s")
 
 
+def test_every_metric_lists_only_cells_that_exist():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
+
+
 def test_every_configuration_has_a_cell_and_its_files():
     used = {w["config"] for w in BENCH["workloads"]}
     for c in BENCH["configs"]:

@@ -246,6 +246,23 @@ def test_recorded_idle_gaps_name_the_program_spans(recorded):
     assert gaps["bench.segment"] < 0.1 * sum(gaps.values())
 
 
+def test_a_traced_run_breaks_its_idle_down_by_program_span(recorded):
+    """`bench/run.py`'s breakdown names the innermost ``fl.*`` span of
+    each idle gap, and splits the same idle time as the ``bench.*``
+    reduction; without the program's view it falls back to that."""
+    from bench import run as bench_run
+    before = bench_trace.breakdown(recorded["trace"])["idle_gaps"]
+    after = bench_run.breakdown(recorded)
+    gaps = dict(after["idle_gaps"])
+    assert {"fl.eval", "fl.host_sync", "fl.prepare"} <= set(gaps)
+    assert sum(gaps.values()) == pytest.approx(
+        sum(v for _, v in before), rel=1e-12)
+    assert after["device_ops"] == bench_trace.breakdown(
+        recorded["trace"])["device_ops"]
+    alone = dict(recorded, program=None)
+    assert bench_run.breakdown(alone)["idle_gaps"] == before
+
+
 def test_recorded_trust_kernel_is_one_op(recorded):
     red = recorded["trace"]
     assert len(bench_trace.op_names(red, trust_kernel_roofline.PATTERN)) == 1

@@ -57,24 +57,67 @@ def test_device_time_of_one_program_leaves_the_others_out():
     assert round_device_ms.read(dict(ctx, trace=red)) is None
 
 
-def test_the_trust_kernel_is_read_by_its_name_alone():
-    op = '%trust_aggregate_global.{} = f32[1,128] custom-call(), ' \
-         'custom_call_target="tpu_custom_call"'
-    other = '%other_kernel.3 = f32[8] custom-call(), ' \
-            'custom_call_target="tpu_custom_call"'
-    spans = [("bench.window", 0, 100)]
-    ctx = {"window": {"rounds": [(0, 1)]}, "members": [4],
-           "spec": {"clustering": {"n_clusters": 4}},
-           "dims": {"dim": 784, "hidden": 200, "n_classes": 10},
-           "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
-    red = bench_trace.reduce([[(op.format(1), 0, 10), (other, 10, 90)]],
-                             spans)
+def _kernel(n):
+    return (f'%trust_aggregate_global.{n} = f32[1,128] custom-call(), '
+            'custom_call_target="tpu_custom_call"')
+
+
+OTHER = ('%other_kernel.3 = f32[8] custom-call(), '
+         'custom_call_target="tpu_custom_call"')
+# three rounds of clusters with 4, 4 and 9 real members
+KERNEL_CTX = {"window": {"rounds": [(0, 1), (1, 1), (2, 3)]},
+              "members": [4, 4, 9],
+              "spec": {"clustering": {"n_clusters": 3}},
+              "dims": {"dim": 784, "hidden": 200, "n_classes": 10},
+              "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
+
+
+def _one_op_reading(ns):
+    """The reading of one kernel op that ran once a round for ``ns``
+    nanoseconds in all."""
+    red = bench_trace.reduce([[(_kernel(1), 0, ns / 3),
+                               (_kernel(1), 100, 100 + ns / 3),
+                               (_kernel(1), 200, 200 + ns / 3)]],
+                             [("bench.window", 0, 1000)])
+    return trust_kernel_roofline.read(dict(KERNEL_CTX, trace=red))
+
+
+@pytest.mark.parametrize("ops,reads", [
+    # one kernel op beside another custom call: only the kernel is read
+    ([(_kernel(1), 0, 10), (OTHER, 10, 90), (_kernel(1), 100, 110),
+      (OTHER, 110, 190), (_kernel(1), 200, 210)], (30e-9, 3)),
+    # a round compiled per cluster width: two kernel ops, one of them in
+    # the rounds of the small clusters, the other in the large one's
+    ([(_kernel(1), 0, 10), (_kernel(1), 100, 110), (_kernel(2), 200, 230)],
+     (50e-9, 3)),
+    # a second call in a round
+    ([(_kernel(1), 0, 10), (_kernel(1), 100, 110), (_kernel(2), 120, 130),
+      (_kernel(1), 200, 210)], None),
+], ids=["beside_another_kernel", "one_op_a_width", "second_call_in_a_round"])
+def test_the_trust_kernel_is_read_by_its_name_alone(ops, reads):
+    """Every op named as the kernel is read, summed over its names; the
+    reading holds for one call a round and raises for more."""
+    red = bench_trace.reduce([ops], [("bench.window", 0, 1000)])
+    ctx = dict(KERNEL_CTX, trace=red)
+    if reads is None:
+        with pytest.raises(ValueError, match="4 times in 3 rounds"):
+            trust_kernel_roofline.read(ctx)
+        return
+    seconds, calls = reads
     assert bench_trace.op_seconds(red, trust_kernel_roofline.PATTERN) == (
-        10e-9, 1)
-    red = bench_trace.reduce([[(op.format(1), 0, 10),
-                               (op.format(2), 20, 30)]], spans)
-    with pytest.raises(ValueError):
-        trust_kernel_roofline.read(dict(ctx, trace=red))
+        pytest.approx(seconds), calls)
+    assert trust_kernel_roofline.read(ctx) == pytest.approx(
+        _one_op_reading(seconds * 1e9))
+
+
+def test_fewer_kernel_calls_than_rounds_scale_by_the_mean():
+    red = bench_trace.reduce([[(_kernel(1), 0, 10), (_kernel(1), 100, 110)]],
+                             [("bench.window", 0, 1000)])
+    least = [trust_kernel_roofline.least_seconds(m, 3, 159010,
+                                                 KERNEL_CTX["peaks"])
+             for m in (4, 4, 9)]
+    assert trust_kernel_roofline.read(dict(KERNEL_CTX, trace=red)) == (
+        pytest.approx(100 * sum(least) / 3 * 2 / 20e-9))
 
 
 def test_breakdown_lists_at_most_ten():
